@@ -39,6 +39,10 @@ def main() -> None:
     if args.quick:
         args.scale = "quick"
 
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from benchmarks import (
         bench_ablation,
         bench_admission,

@@ -216,15 +216,10 @@ class SubprocessReplica:
         self._deadline_s = deadline_s
         self._seq = itertools.count(1)
         self.records = 0
-        from repro.core.shard_rpc import _socket_dir
+        from repro.core.shard_rpc import _socket_dir, child_env
 
         self.path = os.path.join(_socket_dir(),
                                  f"r{next(_sock_counter)}.sock")
-        env = dict(os.environ)
-        src = os.path.dirname(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))))
-        env["PYTHONPATH"] = src + (
-            os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
         # NOT ``-m repro.core.replication``: running the module as __main__
         # would make the standby's MetadataStore pickle as
         # ``__main__.MetadataStore`` and fail to unpickle at takeover.
@@ -233,7 +228,7 @@ class SubprocessReplica:
              "import sys; from repro.core.replication import main; "
              "main(sys.argv[1:])", self.path],
             stdin=subprocess.PIPE, stdout=subprocess.DEVNULL,
-            start_new_session=True, env=env)
+            start_new_session=True, env=child_env())
         self.conn: Optional[socket.socket] = None
         _live_replicas.add(self)
 

@@ -617,16 +617,18 @@ def _fused_spmd_fn(mesh):
     hit = _SPMD_FNS.get(id(mesh))
     if hit is not None:
         return hit[1]
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     @functools.partial(jax.jit, static_argnums=(3,))
     def fn(vals, gid, w, g_pad):
-        body = shard_map(
+        body = jax.shard_map(
             functools.partial(_spmd_body, g_pad=g_pad),
             mesh=mesh,
             in_specs=(P(None, "shards", None),) * 3,
             out_specs=(P(None, None), P(None, None)),
+            # The body's Pallas kernel declares no per-axis variance on its
+            # outputs; the psum makes the results replicated either way.
+            check_vma=False,
         )
         return body(vals, gid, w)
 
@@ -672,6 +674,8 @@ class RouteInfo:
     # True when served by the stacked one-launch SPMD path, False for the
     # per-shard host loop.
     fused: bool = False
+    # Devices the fused launch's stacked inputs span (0 for the host loop).
+    launch_devices: int = 0
     # Queries served by this route (one, or a run_batch hit batch).
     n_queries: int = 1
     # Degraded-mode bookkeeping: ``failed_shards`` lists the shards whose
@@ -855,6 +859,7 @@ class ShardedEngine:
         # and the only path real multi-process RPC shards could take today).
         self.fused = fused
         self._mesh = serving_mesh(use_devices)
+        self._launch_devices = 0
         # Per-shard memory bound: registrations beyond this are pruned by the
         # coordinator's recency clock (``SketchIndex.prune``) after each
         # registration pass, evicting shard maintainers + cached instances.
@@ -1856,6 +1861,10 @@ class ShardedEngine:
         r_max = max((int(a[1].shape[0]) for a in per_shard), default=0)
         r_pad = _next_pow2(max(r_max, 1))
         s_pad = _next_pow2(max(len(per_shard), 1))
+        if self._mesh is not None:
+            # Every mesh device takes an equal run of shard slices (weight-0
+            # filler on the spare ones), so the launch always spans the mesh.
+            s_pad += -s_pad % self._mesh.devices.size
         g_pad = _next_pow2(max(n_groups, 1))
         vals_np = np.zeros((s_pad, r_pad), np.float32)
         gid_np = np.zeros((s_pad, r_pad), np.int32)
@@ -1889,16 +1898,15 @@ class ShardedEngine:
 
     @hot_path
     def _launch(self, vals, gid, weights, g_pad: int):
-        """The one fused launch: shard_map over the serving mesh when its
-        device count divides the (pow2-padded) shard axis, the vmapped
-        single-program path otherwise."""
-        mesh = self._mesh
-        if mesh is not None and vals.shape[1] % mesh.devices.size == 0:
-            fn = _fused_spmd_fn(mesh)
-        else:
-            fn = _fused_jit
+        """The one fused launch: shard_map over the serving mesh when there
+        is one (the stacked shard axis is padded to a multiple of its device
+        count), the single-device program otherwise."""
         LAUNCH_COUNTS["fused_partials"] += 1
-        return fn(vals, gid, weights, g_pad)
+        self._launch_devices = len(vals.sharding.device_set)
+        if self._mesh is None:
+            return _fused_jit(vals, gid, weights, g_pad)
+        LAUNCH_COUNTS["fused_spmd"] += 1
+        return _fused_spmd_fn(self._mesh)(vals, gid, weights, g_pad)
 
     def _result_from_merged(
         self, q: Query, st: StackedInstances,
@@ -1985,6 +1993,7 @@ class ShardedEngine:
             t_merge_s=t_merge,
             t_launch_s=t_launch,
             fused=self.fused,
+            launch_devices=self._launch_devices if self.fused else 0,
             degraded=bool(degraded),
             failed_shards=tuple(sorted(degraded)),
             n_retries=self._route_retries,
@@ -2142,6 +2151,7 @@ class ShardedEngine:
             watermark=self.version, deltas_applied=applied,
             per_shard_s={}, t_merge_s=t1 - tm, t_launch_s=tm - tl,
             fused=True, n_queries=n_served,
+            launch_devices=self._launch_devices,
             degraded=bool(degraded),
             failed_shards=tuple(sorted(degraded)),
             n_retries=self._route_retries,

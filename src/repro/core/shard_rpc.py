@@ -58,6 +58,7 @@ from repro.core.queries import Query, inner_block_arrays
 from repro.core.ranges import RangeSet
 from repro.core.table import ColumnTable
 from repro.runtime import transport
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.guards import hot_path
 
 # Imported lazily where needed to keep `python -m repro.core.shard_rpc`
@@ -272,6 +273,24 @@ def _socket_dir() -> str:
     return _sock_dir
 
 
+def child_env() -> Dict[str, str]:
+    """Environment for a spawned shard server or standby.
+
+    Children import this package from the parent's source tree and run on
+    the CPU: a chip belongs to one process, and on a TPU host the parent
+    (the coordinator) already holds it, so a child that tried to load the
+    TPU runtime would fail or hang.  Subprocess shards are CPU-side by
+    design; the chip path is the one-process ``shard_map`` launch.
+    """
+    env = dict(os.environ)
+    src = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    env["PYTHONPATH"] = src + (
+        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    env["JAX_PLATFORMS"] = "cpu"
+    return env
+
+
 class _ServerProc:
     """One shard server subprocess + its RPC connection."""
 
@@ -387,15 +406,10 @@ class ServerPool:
             if self._closed:
                 raise ShardUnavailableError("server pool is closed")
         path = os.path.join(_socket_dir(), f"s{next(_sock_counter)}.sock")
-        env = dict(os.environ)
-        src = os.path.dirname(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))))
-        env["PYTHONPATH"] = src + (
-            os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
         proc = subprocess.Popen(
             [sys.executable, "-m", "repro.core.shard_rpc", path],
             stdin=subprocess.PIPE, stdout=subprocess.DEVNULL,
-            start_new_session=True, env=env)
+            start_new_session=True, env=child_env())
         sp = _ServerProc(proc, path)
         with self._lock:
             if not self._closed:
@@ -1097,37 +1111,15 @@ def _stdin_watchdog() -> None:
     os._exit(2)
 
 
-def _enable_compile_cache() -> None:
-    """Point this server at the shared on-disk XLA compilation cache.
-
-    Shard servers are short-lived relative to the kernels they compile: a
-    respawned process (post-SIGKILL recovery, pool top-up) would otherwise
-    pay every first-call compile again, which dominates kill->recover
-    wall-clock.  The persistent cache makes those loads instead of
-    compiles.  Opt out with ``REPRO_SHARD_COMPILE_CACHE=""``."""
-    cache_dir = os.environ.get(
-        "REPRO_SHARD_COMPILE_CACHE",
-        os.path.join(tempfile.gettempdir(), "repro-xla-cache"))
-    if not cache_dir:
-        return
-    try:
-        import jax
-
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:
-        pass  # cache is a perf lever, never a correctness dependency
-
-
 def serve(path: str) -> None:
     """The subprocess entry: bind, accept one connection at a time, serve
     request/response until shutdown.  A broken connection (client timed
     out mid-stall and reconnected, coordinator dropped a partition) just
     re-enters accept — shard state survives across connections."""
     threading.Thread(target=_stdin_watchdog, daemon=True).start()
-    _enable_compile_cache()
+    # A respawned server (post-SIGKILL recovery, pool top-up) loads its
+    # kernels from the persistent cache instead of compiling them again.
+    enable_compile_cache()
 
     # Disjoint uid space: tables created in this process (local shard
     # tables, instances) must never collide with coordinator-created uids

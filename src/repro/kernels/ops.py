@@ -1,15 +1,19 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On TPU the Pallas path compiles natively; on the CPU container the kernels
-run under ``interpret=True`` (Python-evaluated kernel bodies) so correctness
-is validated everywhere.  Callers can force the pure-jnp oracle with
-``backend='ref'`` (the default for large CPU workloads, where interpret-mode
-row loops are slow) — the kernels' tests assert the two paths agree.
+On TPU every call runs the Pallas kernel natively (``'pallas'``); there is
+no silent fallback to the reference there.  Elsewhere the default is the
+pure-jnp oracle (``'ref'``), because interpret-mode row loops are slow at
+engine sizes.  A caller picks a mode per call with ``backend=``, or for a
+whole block with :func:`forced_backend` (the CPU rehearsal of the chip path
+runs the engine with ``'interpret'``, which evaluates the same kernel bodies
+on the CPU).  The kernels' tests assert the paths agree.
 """
 from __future__ import annotations
 
+import collections
+import contextlib
 import functools
-from typing import Optional, Tuple
+from typing import Iterator, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -29,15 +33,39 @@ from repro.kernels.sketch_filter import sketch_filter_pallas
 Array = jax.Array
 
 
+MODES = ("pallas", "interpret", "ref")
+
+# Dispatches per (kernel, mode), counted when a wrapper runs on the host —
+# once per call, or once per trace when an enclosing jit calls it.  Shows
+# which implementation a run actually used.
+DISPATCHES: collections.Counter = collections.Counter()
+
+_forced: Optional[str] = None
+
+
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _mode(backend: Optional[str]) -> str:
+@contextlib.contextmanager
+def forced_backend(mode: str) -> Iterator[None]:
+    """Run every kernel call in the block that passes no ``backend=`` in
+    ``mode``."""
+    global _forced
+    if mode not in MODES:
+        raise ValueError(f"unknown kernel mode {mode!r}")
+    prev, _forced = _forced, mode
+    try:
+        yield
+    finally:
+        _forced = prev
+
+
+def _mode(kernel: str, backend: Optional[str]) -> str:
     """'pallas' | 'interpret' | 'ref'."""
-    if backend is not None:
-        return backend
-    return "pallas" if _on_tpu() else "ref"
+    mode = backend or _forced or ("pallas" if _on_tpu() else "ref")
+    DISPATCHES[(kernel, mode)] += 1
+    return mode
 
 
 @functools.partial(jax.jit, static_argnums=(2, 3))
@@ -50,7 +78,7 @@ def _fragment_bitmap_jit(prov, bucket, n_ranges, mode):
 
 
 def fragment_bitmap(prov: Array, bucket: Array, n_ranges: int, backend: Optional[str] = None) -> Array:
-    return _fragment_bitmap_jit(prov, bucket, n_ranges, _mode(backend))
+    return _fragment_bitmap_jit(prov, bucket, n_ranges, _mode("fragment_bitmap", backend))
 
 
 @functools.partial(jax.jit, static_argnums=(2, 3))
@@ -66,7 +94,8 @@ def fragment_bitmap_batch(
     provs: Array, bucket: Array, n_ranges: int, backend: Optional[str] = None
 ) -> Array:
     """B stacked provenance masks -> B sketch bitvectors, one scan."""
-    return _fragment_bitmap_batch_jit(provs, bucket, n_ranges, _mode(backend))
+    return _fragment_bitmap_batch_jit(
+        provs, bucket, n_ranges, _mode("fragment_bitmap_batch", backend))
 
 
 @functools.partial(jax.jit, static_argnums=(2,))
@@ -79,7 +108,7 @@ def _sketch_filter_jit(bucket, bits, mode):
 
 
 def sketch_filter(bucket: Array, bits: Array, backend: Optional[str] = None) -> Array:
-    return _sketch_filter_jit(bucket, bits, _mode(backend))
+    return _sketch_filter_jit(bucket, bits, _mode("sketch_filter", backend))
 
 
 @functools.partial(jax.jit, static_argnums=(2, 4))
@@ -98,7 +127,8 @@ def segment_aggregate(
     weights: Optional[Array] = None,
     backend: Optional[str] = None,
 ) -> Tuple[Array, Array]:
-    return _segment_aggregate_jit(values, gid, n_groups, weights, _mode(backend))
+    return _segment_aggregate_jit(
+        values, gid, n_groups, weights, _mode("segment_aggregate", backend))
 
 
 @functools.partial(jax.jit, static_argnums=(2, 4))
@@ -123,7 +153,8 @@ def segment_aggregate_batch(
     The sharded serving engine flattens its (query, shard) axes into ``B`` so
     every shard's per-group partials come out of one launch.
     """
-    return _segment_aggregate_batch_jit(values, gid, n_groups, weights, _mode(backend))
+    return _segment_aggregate_batch_jit(
+        values, gid, n_groups, weights, _mode("segment_aggregate_batch", backend))
 
 
 @functools.partial(jax.jit, static_argnums=(3, 4, 5))
@@ -140,4 +171,4 @@ def flash_attention(
     backend: Optional[str] = None,
 ) -> Array:
     """Dispatches Pallas on TPU, reference math elsewhere (used by models)."""
-    return _flash_attention_jit(q, k, v, causal, window, _mode(backend))
+    return _flash_attention_jit(q, k, v, causal, window, _mode("flash_attention", backend))

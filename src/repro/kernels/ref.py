@@ -48,6 +48,10 @@ ONEHOT_MAX_GROUPS = 128
 # rematerialized per tile inside a scan (mirroring the Pallas kernel's row
 # tiles) so it stays cache-resident instead of spilling a (B, n, G) tensor.
 ONEHOT_TILE_ROWS = 16384
+# The one-hot dots carry arbitrary float32 values.  At default precision a
+# TPU rounds float32 matmul operands to bf16, which is inexact for integers
+# above 256; HIGHEST keeps them float32 (the CPU ignores the setting).
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 def _pow2_tiles(n: int, target: int) -> int:
@@ -82,7 +86,7 @@ def segment_aggregate_batch_ref(
         tiles = _pow2_tiles(n, max(ONEHOT_TILE_ROWS // max(b, 1), 1))
         if tiles == 1:
             onehot = (gid[..., None] == groups).astype(jnp.float32)
-            out = jnp.einsum("bkn,bng->bkg", vw, onehot)
+            out = jnp.einsum("bkn,bng->bkg", vw, onehot, precision=_EXACT)
             return out[:, 0], out[:, 1]
         tn = n // tiles
         vw_t = vw.reshape(b, 2, tiles, tn).transpose(2, 0, 1, 3)  # (T, B, 2, tn)
@@ -91,7 +95,8 @@ def segment_aggregate_batch_ref(
         def step(acc, xs):
             vwk, gk = xs
             onehot = (gk[..., None] == groups).astype(jnp.float32)
-            return acc + jnp.einsum("bkn,bng->bkg", vwk, onehot), None
+            return acc + jnp.einsum("bkn,bng->bkg", vwk, onehot,
+                                    precision=_EXACT), None
 
         acc, _ = jax.lax.scan(
             step, jnp.zeros((b, 2, n_groups), jnp.float32), (vw_t, g_t))

@@ -1,16 +1,21 @@
 """Pallas TPU kernel: segmented aggregation (group-by SUM/COUNT hot loop).
 
-Hash aggregation does not map to the TPU; the MXU does.  For a row tile and a
-group block we materialize the one-hot membership matrix in VMEM and issue a
-single (groups x rows) @ (rows x 2) matmul producing the per-group [sum,
-count] partials, accumulated in the VMEM-resident output block across row
-tiles.  A 2-D grid (group blocks x row tiles) scales to group counts far
-beyond one block: the inner (row) dimension iterates fastest so each group
-block's accumulator stays resident while rows stream.
+Rows stay on lanes throughout.  Each grid step streams a ``(sub, 128)`` tile
+of (group id, value, weight); for every sublane row of the tile the kernel
+compares that ``(1, 128)`` row of group ids against a sublane iota of the
+group block, giving a ``(group_block, 128)`` one-hot, and accumulates
+``where(onehot, value * weight, 0)`` / ``where(onehot, weight, 0)`` into
+lane-dense ``(group_block, 128)`` output blocks that stay resident in VMEM
+while the row tiles of one (batch, group block) stream past.  The 128 lane
+partials of each group are summed by XLA after the launch.
 
-MXU alignment: the contraction dim is the row tile (2048 = 16*128) and the
-output dims are (GROUP_BLOCK, 128-lane pairs); both multiples of the 128x128
-systolic tile.
+All arithmetic is float32 adds on the VPU: no matmul, so no operand is ever
+rounded to bf16.  On integral inputs whose per-group sums stay below 2^24
+every association order is exact, so the result is bit-identical to a
+float64 reference.
+
+A 3-D grid (batch x group blocks x row tiles) covers B independent segment
+problems in one launch; the single-problem entry point is the B = 1 case.
 """
 from __future__ import annotations
 
@@ -20,55 +25,33 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-ROWS_PER_TILE = 2048
+ROWS_PER_TILE = 8192
 GROUP_BLOCK = 512
 LANE = 128
+SUBLANE = 8
 
 
-def _segagg_kernel(gid_ref, val_ref, w_ref, out_ref, *, group_block: int):
-    g = pl.program_id(0)
-    r = pl.program_id(1)
+def _segagg_kernel(gid_ref, val_ref, w_ref, sum_ref, cnt_ref, *, group_block: int):
+    g = pl.program_id(1)
 
-    @pl.when(r == 0)
+    @pl.when(pl.program_id(2) == 0)
     def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
+        sum_ref[...] = jnp.zeros_like(sum_ref)
+        cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
-    gid = gid_ref[...].reshape(-1)  # (rows,)
-    vals = val_ref[...].reshape(-1).astype(jnp.float32)
-    w = w_ref[...].reshape(-1).astype(jnp.float32)
-    rows = gid.shape[0]
+    groups = (jax.lax.broadcasted_iota(jnp.int32, (group_block, LANE), 0)
+              + g * group_block)
 
-    local = gid - g * group_block
-    group_ids = jax.lax.broadcasted_iota(jnp.int32, (rows, group_block), 1)
-    onehot = (local[:, None] == group_ids).astype(jnp.float32)  # (rows, G)
-    # (G, rows) @ (rows, 2) on the MXU: columns are [sum, count].
-    vw = jnp.stack([vals * w, w], axis=1)  # (rows, 2)
-    partial = jax.lax.dot_general(
-        onehot, vw, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (G, 2)
-    out_ref[...] += partial.reshape(out_ref.shape)
+    def row(j, carry):
+        gid = gid_ref[pl.ds(j, 1), :]  # (1, 128)
+        w = w_ref[pl.ds(j, 1), :]
+        vw = val_ref[pl.ds(j, 1), :] * w
+        hit = gid == groups  # (group_block, 128)
+        sum_ref[...] += jnp.where(hit, vw, 0.0)
+        cnt_ref[...] += jnp.where(hit, w, 0.0)
+        return carry
 
-
-def _segagg_batch_kernel(gid_ref, val_ref, w_ref, out_ref, *, group_block: int):
-    r = pl.program_id(2)
-
-    @pl.when(r == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    gid = gid_ref[...].reshape(-1)
-    vals = val_ref[...].reshape(-1).astype(jnp.float32)
-    w = w_ref[...].reshape(-1).astype(jnp.float32)
-    rows = gid.shape[0]
-
-    local = gid - pl.program_id(1) * group_block
-    group_ids = jax.lax.broadcasted_iota(jnp.int32, (rows, group_block), 1)
-    onehot = (local[:, None] == group_ids).astype(jnp.float32)
-    vw = jnp.stack([vals * w, w], axis=1)
-    partial = jax.lax.dot_general(
-        onehot, vw, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    out_ref[...] += partial.reshape(out_ref.shape)
+    jax.lax.fori_loop(0, gid_ref.shape[0], row, 0)
 
 
 def segment_aggregate_batch_pallas(
@@ -91,12 +74,17 @@ def segment_aggregate_batch_pallas(
     b, n = values.shape
     w = (jnp.ones_like(values, dtype=jnp.float32) if weights is None
          else weights.astype(jnp.float32))
+    # Short inputs take a short tile rather than padding to a full one.
+    rows_per_tile = min(rows_per_tile, max(SUBLANE * LANE, -(-n // LANE) * LANE))
+    rows_per_tile += -rows_per_tile % (SUBLANE * LANE)
+    group_block = min(group_block, n_groups + (-n_groups % SUBLANE))
     n_pad = -n % rows_per_tile
+    # Padded rows get gid = -1: they match no group block.
     gid_p = jnp.pad(gid.astype(jnp.int32), ((0, 0), (0, n_pad)), constant_values=-1)
     val_p = jnp.pad(values.astype(jnp.float32), ((0, 0), (0, n_pad)))
     w_p = jnp.pad(w, ((0, 0), (0, n_pad)))
     n_tiles = (n + n_pad) // rows_per_tile
-    n_gblocks = (n_groups + group_block - 1) // group_block
+    n_gblocks = -(-n_groups // group_block)
     sub = rows_per_tile // LANE
 
     gid_2d = gid_p.reshape(b * n_tiles * sub, LANE)
@@ -104,16 +92,19 @@ def segment_aggregate_batch_pallas(
     w_2d = w_p.reshape(b * n_tiles * sub, LANE)
 
     in_spec = pl.BlockSpec((sub, LANE), lambda i, g, r: (i * n_tiles + r, 0))
-    out = pl.pallas_call(
-        functools.partial(_segagg_batch_kernel, group_block=group_block),
+    out_spec = pl.BlockSpec((group_block, LANE), lambda i, g, r: (i * n_gblocks + g, 0))
+    out_shape = jax.ShapeDtypeStruct((b * n_gblocks * group_block, LANE), jnp.float32)
+    sums, counts = pl.pallas_call(
+        functools.partial(_segagg_kernel, group_block=group_block),
         grid=(b, n_gblocks, n_tiles),
         in_specs=[in_spec, in_spec, in_spec],
-        out_specs=pl.BlockSpec((group_block, 2), lambda i, g, r: (i * n_gblocks + g, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * n_gblocks * group_block, 2), jnp.float32),
+        out_specs=[out_spec, out_spec],
+        out_shape=[out_shape, out_shape],
         interpret=interpret,
     )(gid_2d, val_2d, w_2d)
-    out = out.reshape(b, n_gblocks * group_block, 2)
-    return out[:, :n_groups, 0], out[:, :n_groups, 1]
+    sums = sums.reshape(b, n_gblocks * group_block, LANE).sum(axis=-1)
+    counts = counts.reshape(b, n_gblocks * group_block, LANE).sum(axis=-1)
+    return sums[:, :n_groups], counts[:, :n_groups]
 
 
 def segment_aggregate_pallas(
@@ -125,34 +116,10 @@ def segment_aggregate_pallas(
     group_block: int = GROUP_BLOCK,
     interpret: bool = False,
 ):
-    """(sums f32[n_groups], counts f32[n_groups]) via one-hot MXU matmuls."""
-    n = values.shape[0]
-    w = jnp.ones_like(values, dtype=jnp.float32) if weights is None else weights.astype(jnp.float32)
-    n_pad = -n % rows_per_tile
-    # Padded rows get gid = -1: they match no group block.
-    gid_p = jnp.pad(gid.astype(jnp.int32), (0, n_pad), constant_values=-1)
-    val_p = jnp.pad(values.astype(jnp.float32), (0, n_pad))
-    w_p = jnp.pad(w, (0, n_pad))
-    n_tiles = (n + n_pad) // rows_per_tile
-    n_gblocks = (n_groups + group_block - 1) // group_block
-    sub = rows_per_tile // LANE
-
-    gid_2d = gid_p.reshape(n_tiles * sub, LANE)
-    val_2d = val_p.reshape(n_tiles * sub, LANE)
-    w_2d = w_p.reshape(n_tiles * sub, LANE)
-
-    out = pl.pallas_call(
-        functools.partial(_segagg_kernel, group_block=group_block),
-        grid=(n_gblocks, n_tiles),
-        in_specs=[
-            pl.BlockSpec((sub, LANE), lambda g, r: (r, 0)),
-            pl.BlockSpec((sub, LANE), lambda g, r: (r, 0)),
-            pl.BlockSpec((sub, LANE), lambda g, r: (r, 0)),
-        ],
-        out_specs=pl.BlockSpec((group_block, 2), lambda g, r: (g, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_gblocks * group_block, 2), jnp.float32),
-        interpret=interpret,
-    )(gid_2d, val_2d, w_2d)
-    sums = out[:n_groups, 0]
-    counts = out[:n_groups, 1]
-    return sums, counts
+    """(sums f32[n_groups], counts f32[n_groups]): the B = 1 batched launch."""
+    sums, counts = segment_aggregate_batch_pallas(
+        values[None], gid[None], n_groups,
+        None if weights is None else weights[None],
+        rows_per_tile=rows_per_tile, group_block=group_block,
+        interpret=interpret)
+    return sums[0], counts[0]

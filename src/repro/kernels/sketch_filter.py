@@ -1,35 +1,39 @@
 """Pallas TPU kernel: sketch application (the data-skipping scan).
 
 ``keep[i] = bits[bucket[i]]`` — translating a sketch into a row keep-mask.
-TPUs have no fast arbitrary gather, so the lookup is expressed as a one-hot
-contraction against the bitmap, which the compiler maps onto the VPU: for a
-row tile we compute ``max_r bits[r] * (bucket == r)``.  The bitmap block is
-pinned in VMEM across the grid; row tiles stream through with the usual
-double buffering.  On real partitioned tables the fragment-major layout makes
-``bits`` constant per tile, degenerating this to a broadcast — that case is
-handled upstream by simply not scheduling skipped fragments (see
-``repro/data/pipeline.py``); this kernel covers the unsorted fallback.
+TPUs have no fast arbitrary gather, so the lookup is a one-hot select with
+rows kept on lanes: for every sublane row of a ``(sub, 128)`` bucket tile
+the kernel compares that ``(1, 128)`` row against a sublane iota of range
+ids and takes the max over ranges of ``where(onehot, bits, 0)``, where the
+bitmap arrives pre-broadcast along lanes as a ``(ranges, 128)`` block pinned
+in VMEM across the grid.  The ``(1, 128)`` result is written straight into
+its row of the output tile.  On a fragment-major clustered table the sketch
+instance is a slice concatenation instead (``ColumnTable.take_fragments``);
+this kernel covers the unclustered layout.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-ROWS_PER_TILE = 2048
+ROWS_PER_TILE = 8192
 LANE = 128
+SUBLANE = 8
 
 
-def _filter_kernel(bucket_ref, bits_ref, out_ref, *, n_ranges_p: int):
-    bucket = bucket_ref[...].reshape(-1)  # (rows,)
-    bits = bits_ref[...].reshape(-1)  # (n_ranges_p,)
-    rows = bucket.shape[0]
-    range_ids = jax.lax.broadcasted_iota(jnp.int32, (rows, n_ranges_p), 1)
-    onehot = (bucket[:, None] == range_ids).astype(jnp.int32)
-    keep = jnp.max(onehot * bits[None, :], axis=1)  # (rows,)
-    out_ref[...] = keep.reshape(out_ref.shape)
+def _filter_kernel(bucket_ref, bits_ref, out_ref):
+    n_ranges_p = bits_ref.shape[0]
+    range_ids = jax.lax.broadcasted_iota(jnp.int32, (n_ranges_p, LANE), 0)
+    bits = bits_ref[...]  # (ranges, 128): bits[r] repeated along lanes
+
+    def row(j, carry):
+        hit = bucket_ref[pl.ds(j, 1), :] == range_ids
+        out_ref[pl.ds(j, 1), :] = jnp.max(
+            jnp.where(hit, bits, 0), axis=0, keepdims=True)
+        return carry
+
+    jax.lax.fori_loop(0, bucket_ref.shape[0], row, 0)
 
 
 def sketch_filter_pallas(
@@ -41,22 +45,24 @@ def sketch_filter_pallas(
     """keep (bool[n]) from bucket (int32[n]) and bits (bool[n_ranges])."""
     n = bucket.shape[0]
     n_ranges = bits.shape[0]
+    rows_per_tile = min(rows_per_tile, max(SUBLANE * LANE, -(-n // LANE) * LANE))
+    rows_per_tile += -rows_per_tile % (SUBLANE * LANE)
     n_pad = -n % rows_per_tile
     bucket_p = jnp.pad(bucket.astype(jnp.int32), (0, n_pad))
-    n_ranges_p = n_ranges + (-n_ranges % LANE)
+    n_ranges_p = n_ranges + (-n_ranges % SUBLANE)
     bits_p = jnp.pad(bits.astype(jnp.int32), (0, n_ranges_p - n_ranges))
     n_tiles = (n + n_pad) // rows_per_tile
     sub = rows_per_tile // LANE
 
     bucket_2d = bucket_p.reshape(n_tiles * sub, LANE)
-    bits_2d = bits_p.reshape(1, n_ranges_p)
+    bits_2d = jnp.broadcast_to(bits_p[:, None], (n_ranges_p, LANE))
 
     out = pl.pallas_call(
-        functools.partial(_filter_kernel, n_ranges_p=n_ranges_p),
+        _filter_kernel,
         grid=(n_tiles,),
         in_specs=[
             pl.BlockSpec((sub, LANE), lambda i: (i, 0)),
-            pl.BlockSpec((1, n_ranges_p), lambda i: (0, 0)),
+            pl.BlockSpec((n_ranges_p, LANE), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((sub, LANE), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_tiles * sub, LANE), jnp.int32),

@@ -37,13 +37,11 @@ def place_stacked(arr: jax.Array, mesh, shard_axis: int = 1) -> jax.Array:
     ``arr``'s ``shard_axis`` is laid out over the mesh's ``"shards"`` axis
     (every device owns a contiguous run of shard slices) so the fused
     shard_map launch reads its shard's rows locally.  Identity when there is
-    no mesh or the axis does not divide evenly (the vmapped single-program
-    fallback then runs wherever the arrays already live).
+    no mesh.  The caller pads the axis to a multiple of the mesh's device
+    count; an axis that does not divide is an error, not a quiet fallback
+    to one device.
     """
     if mesh is None:
-        return arr
-    n_dev = mesh.devices.size
-    if arr.shape[shard_axis] % n_dev != 0:
         return arr
     spec = [None] * arr.ndim
     spec[shard_axis] = "shards"

@@ -105,7 +105,7 @@ def retrace_guard(allowed: Optional[int] = 0, label: str = "") -> Iterator[Compi
     which is exactly the "steady state compiles nothing new" contract the
     pow2 padding exists to uphold.
     """
-    from jax._src import monitoring
+    from jax import monitoring
 
     watch = CompileWatch()
 
@@ -117,7 +117,7 @@ def retrace_guard(allowed: Optional[int] = 0, label: str = "") -> Iterator[Compi
     try:
         yield watch
     finally:
-        monitoring._unregister_event_duration_listener_by_callback(listener)
+        monitoring.unregister_event_duration_listener(listener)
     if allowed is not None and watch.compiles > allowed:
         where = f" [{label}]" if label else ""
         raise RetraceError(

@@ -58,6 +58,16 @@ def test_retrace_guard_observe_mode_counts():
     assert watch.compiles == 0
 
 
+def test_retrace_guard_unregisters_when_block_raises():
+    """A block that raises leaves no listener behind: later compiles are
+    not counted into its watch."""
+    with pytest.raises(KeyError):
+        with retrace_guard(allowed=None) as watch:
+            raise KeyError("boom")
+    _double(jnp.ones(48))  # a fresh compile after the block
+    assert watch.compiles == 0
+
+
 # -- launch guard -----------------------------------------------------------
 
 
@@ -181,6 +191,7 @@ def test_select_key_hash_stable_across_processes():
             [sys.executable, "-c", _HASH_SCRIPT],
             capture_output=True, text=True, cwd=REPO,
             env={"PYTHONPATH": str(REPO / "src"), "PYTHONHASHSEED": seed,
+                 "JAX_PLATFORMS": "cpu",
                  "PATH": "/usr/bin:/bin:/usr/local/bin"})
         assert proc.returncode == 0, proc.stderr
         outs.append(proc.stdout)

@@ -79,6 +79,41 @@ def test_segment_aggregate_matches_engine_groupby():
     np.testing.assert_allclose(np.asarray(s1), want, rtol=1e-4)
 
 
+def test_forced_backend_selects_mode_and_is_counted():
+    """``forced_backend`` steers calls that pass no ``backend=``, restores
+    the default on exit, and every dispatch is counted per mode."""
+    bucket = jnp.asarray(RNG.integers(0, 7, 300).astype(np.int32))
+    bits = jnp.asarray(RNG.random(7) < 0.5)
+    before = ops.DISPATCHES[("sketch_filter", "interpret")]
+    with ops.forced_backend("interpret"):
+        got = ops.sketch_filter(bucket, bits)
+    assert ops.DISPATCHES[("sketch_filter", "interpret")] == before + 1
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(ref.sketch_filter_ref(bucket, bits)))
+    assert ops._forced is None
+    with pytest.raises(ValueError):
+        with ops.forced_backend("tpu"):
+            pass
+
+
+@pytest.mark.parametrize("n,g", [(5000, 37), (20000, 512)])
+def test_segment_aggregate_exact_above_bf16_range(n, g):
+    """Integral values above 256 (not exact in bf16) with per-group sums
+    below 2^24: the kernel equals a float64 reference bit for bit."""
+    gid = RNG.integers(0, g, n).astype(np.int32)
+    vals = RNG.integers(257, 4097, n).astype(np.float32)
+    w = (RNG.random(n) < 0.75).astype(np.float32)
+    want = np.bincount(gid, weights=vals.astype(np.float64) * w, minlength=g)
+    for backend in ("interpret", "ref"):
+        s, c = ops.segment_aggregate(jnp.asarray(vals), jnp.asarray(gid), g,
+                                     jnp.asarray(w), backend=backend)
+        np.testing.assert_array_equal(np.asarray(s, np.float64), want)
+    sb, _ = ops.segment_aggregate_batch(
+        jnp.asarray(np.stack([vals, vals[::-1]])), jnp.asarray(np.stack([gid, gid[::-1]])),
+        g, jnp.asarray(np.stack([w, w[::-1]])), backend="interpret")
+    np.testing.assert_array_equal(np.asarray(sb[0], np.float64), want)
+
+
 @pytest.mark.parametrize("s,t", [(64, 64), (96, 96), (1, 96)])
 @pytest.mark.parametrize("causal,window", [(True, 0), (True, 32), (False, 0)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -209,3 +209,44 @@ def test_plan_replacement_invariants():
 def test_plan_replacement_no_survivors():
     with pytest.raises(ValueError):
         plan_replacement(np.array([1.0]), np.array([0]), 2, dead=[0, 1])
+
+
+# -- process boundaries: compile cache placement, child environments --------
+
+_CACHE_PROBE = (
+    "import jax\n"
+    "from repro.runtime.compile_cache import enable_compile_cache\n"
+    "print(enable_compile_cache())\n"
+    "print(jax.config.jax_compilation_cache_dir)\n")
+
+
+@pytest.mark.parametrize("from_env", [False, True])
+def test_compile_cache_dir_follows_env_else_fixed_checkout_path(tmp_path, from_env):
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    repo = Path(__file__).resolve().parents[1]
+    env = {"PYTHONPATH": str(repo / "src"), "JAX_PLATFORMS": "cpu",
+           "PATH": os.environ.get("PATH", "/usr/bin:/bin")}
+    want = str(repo / ".jax_cache")
+    if from_env:
+        want = str(tmp_path / "cache")
+        env["JAX_COMPILATION_CACHE_DIR"] = want
+    proc = subprocess.run([sys.executable, "-c", _CACHE_PROBE], env=env,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr
+    assert proc.stdout.split() == [want, want]
+
+
+def test_child_env_pins_children_to_the_cpu(monkeypatch):
+    """Spawned shard servers and standbys never try to take the chip."""
+    import os
+
+    from repro.core.shard_rpc import child_env
+
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    env = child_env()
+    assert env["JAX_PLATFORMS"] == "cpu"
+    assert os.path.isdir(os.path.join(env["PYTHONPATH"].split(os.pathsep)[0], "repro"))

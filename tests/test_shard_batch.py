@@ -397,7 +397,7 @@ def test_spmd_mesh_shard_map_path():
         assert res.canonical() == execute(q, se.db).canonical()
         print("SPMD_OK")
     """)
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=4").strip()
     env["PYTHONPATH"] = (os.path.join(os.path.dirname(__file__), "..", "src")
