@@ -42,7 +42,6 @@ sequential replay that re-sampled the permuted rows.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import TYPE_CHECKING, Dict, List, Optional, Tuple
 
 import jax
@@ -71,12 +70,13 @@ from repro.core.strategies import (
     select_attribute,
     selection_cache_key,
 )
+from repro.runtime import trace
 from repro.runtime.guards import hot_path
 
 if TYPE_CHECKING:  # pragma: no cover - typing only
     from repro.core.engine import PBDSEngine, RunInfo
 
-Miss = Tuple[int, Query, float]  # (batch position, query, probe seconds)
+Miss = Tuple[int, Query, trace.Record]  # (batch position, query, its record)
 
 
 def exec_group_key(q: Query) -> Tuple:
@@ -212,10 +212,12 @@ def _select_wave(
         # what a sequential replay would sample with.
         q0 = pending[0][1][0][1]
         k_s, k_e = jax.random.split(engine._select_key(q0))
-        samples = engine.samples.get_or_create(
-            k_s, db[q0.table], q0.groupby_on_fact(db), engine.theta)
-        est, sampled = engine.aqr.get_or_compute(
-            k_e, q0, db, samples, engine.theta, engine.cfg)
+        with trace.span("repro.select.sample"):
+            samples = engine.samples.get_or_create(
+                k_s, db[q0.table], q0.groupby_on_fact(db), engine.theta)
+        with trace.span("repro.select.aqr"):
+            est, sampled = engine.aqr.get_or_compute(
+                k_e, q0, db, samples, engine.theta, engine.cfg)
         for ck, bmembers, cands in pending:
             bq = bmembers[0][1]
             specs.append(EstimationSpec(
@@ -226,7 +228,8 @@ def _select_wave(
             ))
             spec_assign.append((ck, [pos for pos, _ in bmembers]))
     if specs:
-        all_estimates = estimate_size_multi(db, specs, engine.cfg, engine.catalog)
+        with trace.span("repro.select.estimate"):
+            all_estimates = estimate_size_multi(db, specs, engine.cfg, engine.catalog)
         for spec, (ck, positions), estimates in zip(specs, spec_assign,
                                                     all_estimates):
             # Tuple tie-break (attr name second): must match the sequential
@@ -248,17 +251,22 @@ def admit_wave(
     engine: "PBDSEngine", wave: List[Miss]
 ) -> Dict[int, Tuple[QueryResult, "RunInfo"]]:
     """Run one wave of misses through the shared pipeline; returns per-batch-
-    position ``(result, info)`` exactly like ``PBDSEngine.run`` would."""
+    position ``(result, info)`` exactly like ``PBDSEngine.run`` would.
+
+    The wave's shared work is timed on the batch's record (``repro.select``,
+    ``repro.scan``, ``repro.capture`` > ``repro.capture.bitmap``); each
+    member's record points to it and takes an equal share, and holds its own
+    tail (``repro.finish``, ``repro.capture.maintainer``/``.warm``)."""
     from repro.core.engine import RunInfo
     from repro.core.maintenance import SketchMaintainer
 
     catalog = engine.catalog
     out: Dict[int, Tuple[QueryResult, RunInfo]] = {}
-    probe_s = {pos: tp for pos, _, tp in wave}
+    recs = {pos: rec for pos, _, rec in wave}
 
-    t0 = time.perf_counter()
-    sels = _select_wave(engine, wave)
-    t_select_each = (time.perf_counter() - t0) / max(len(wave), 1)
+    with trace.span("repro.select") as select:
+        sels = _select_wave(engine, wave)
+    t_select = {pos: recs[pos].share(select, len(wave)) for pos in recs}
 
     # Worth-it partition — ``PBDSEngine._worth_it``, the same rule as ``run``
     # including the reuse-aware discount.  Misses are logged in wave order
@@ -293,17 +301,16 @@ def admit_wave(
     provs: Dict[int, np.ndarray] = {}
     t_exec: Dict[int, float] = {}
     for members in exec_groups.values():
-        te0 = time.perf_counter()
-        ib = inner_block(db, members[0][1], catalog)
-        ib_share = (time.perf_counter() - te0) / len(members)
+        with trace.span("repro.scan") as scan:
+            ib = inner_block(db, members[0][1], catalog)
         n_fact = db[members[0][1].table].num_rows
         for pos, q in members:
-            tq0 = time.perf_counter()
-            results[pos] = result_from_group_state(
-                q, ib.group_values, ib.agg_np, ib.present)
-            if pos in admitted:
-                provs[pos] = provenance_from_inner(q, ib, n_fact)
-            t_exec[pos] = ib_share + (time.perf_counter() - tq0)
+            with trace.recording(recs[pos]), trace.span("repro.finish") as finish:
+                results[pos] = result_from_group_state(
+                    q, ib.group_values, ib.agg_np, ib.present)
+                if pos in admitted:
+                    provs[pos] = provenance_from_inner(q, ib, n_fact)
+            t_exec[pos] = recs[pos].share(scan, len(members)) + finish.seconds
 
     # Fused capture: one bucketize + one batched bitmap launch per partition.
     adm_pos = [pos for pos, _, _ in wave if pos in admitted]
@@ -311,49 +318,55 @@ def admit_wave(
     sketches: Dict[int, object] = {}
     if adm_pos:
         q_of = {pos: q for pos, q, _ in wave}
-        tc0 = time.perf_counter()
-        sk_list = capture_sketches_batch(
-            [q_of[pos] for pos in adm_pos], db,
-            [admitted[pos] for pos in adm_pos],
-            [provs[pos] for pos in adm_pos], catalog=catalog)
-        cap_share = (time.perf_counter() - tc0) / len(adm_pos)
-        sketches = dict(zip(adm_pos, sk_list))
+        with trace.span("repro.capture"):
+            with trace.span("repro.capture.bitmap") as bitmap:
+                sk_list = capture_sketches_batch(
+                    [q_of[pos] for pos in adm_pos], db,
+                    [admitted[pos] for pos in adm_pos],
+                    [provs[pos] for pos in adm_pos], catalog=catalog)
+            sketches = dict(zip(adm_pos, sk_list))
 
-        # Maintainer counting state is HAVING-independent: build once per
-        # (signature group, partition), clone for the rest of the group.
-        bases: Dict[Tuple, SketchMaintainer] = {}
-        for pos in adm_pos:
-            q, ranges, sketch = q_of[pos], admitted[pos], sketches[pos]
-            tm0 = time.perf_counter()
-            bk = (exec_group_key(q), ranges.key())
-            base = bases.get(bk)
-            if base is None:
-                maintainer = SketchMaintainer(q, db, ranges, catalog)
-                bases[bk] = maintainer
-            else:
-                maintainer = base.clone_for(q, db, catalog)
-            engine.index.insert(q, sketch, maintainer=maintainer)
-            # Warm the reuse path while we are already paying capture cost
-            # (instance materialization + compiled shapes), same as ``run``.
-            execute(q, apply_sketch(sketch, db, catalog=catalog), catalog=catalog)
-            t_capture[pos] = cap_share + (time.perf_counter() - tm0)
+            # Maintainer counting state is HAVING-independent: build once per
+            # (signature group, partition), clone for the rest of the group.
+            bases: Dict[Tuple, SketchMaintainer] = {}
+            for pos in adm_pos:
+                q, ranges, sketch = q_of[pos], admitted[pos], sketches[pos]
+                with trace.recording(recs[pos]):
+                    with trace.span("repro.capture.maintainer") as build:
+                        bk = (exec_group_key(q), ranges.key())
+                        base = bases.get(bk)
+                        if base is None:
+                            maintainer = SketchMaintainer(q, db, ranges, catalog)
+                            bases[bk] = maintainer
+                        else:
+                            maintainer = base.clone_for(q, db, catalog)
+                        engine.index.insert(q, sketch, maintainer=maintainer)
+                    # Warm the reuse path while we are already paying capture
+                    # cost (instance materialization + compiled shapes), same
+                    # as ``run``.
+                    with trace.span("repro.capture.warm") as warm:
+                        execute(q, apply_sketch(sketch, db, catalog=catalog),
+                                catalog=catalog)
+                t_capture[pos] = (recs[pos].share(bitmap, len(adm_pos))
+                                  + build.seconds + warm.seconds)
 
     for pos, q, _ in wave:
         sel = sels[pos]
+        probe = recs[pos].last("repro.probe")
         if pos in sketches:
             sketch = sketches[pos]
             info = RunInfo(
                 reused=False, created=True, attr=sel.attr,
                 strategy=engine.strategy, selectivity=sketch.selectivity,
-                t_probe=probe_s[pos], t_select=t_select_each,
-                t_capture=t_capture[pos], t_execute=t_exec[pos],
+                t_probe=probe.seconds, t_select=t_select[pos],
+                t_capture=t_capture[pos], t_execute=t_exec[pos], trace=recs[pos],
             )
         else:
             info = RunInfo(
                 reused=False, created=False, attr=None,
                 strategy=engine.strategy, selectivity=None,
-                t_probe=probe_s[pos], t_select=t_select_each,
-                t_execute=t_exec[pos],
+                t_probe=probe.seconds, t_select=t_select[pos],
+                t_execute=t_exec[pos], trace=recs[pos],
             )
         out[pos] = (results[pos], info)
     return out

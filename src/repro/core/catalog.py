@@ -209,6 +209,20 @@ class Catalog:
         # the catalog (a ``shard.StackedInstances``).
         self._stacked: Dict[Tuple, Tuple[Tuple, object]] = {}
 
+    # The ``stats`` keys of lookups that did work instead of returning a
+    # cached product: full builds, delta refreshes and instance derivations.
+    MISS_STATS = (
+        "encode_groups", "encode_groups_instance", "encode_groups_delta",
+        "bucketize", "bucketize_delta", "frag_of_group",
+        "fragment_sizes", "fragment_sizes_delta",
+        "where_mask", "where_mask_instance", "where_mask_delta",
+        "join_materialize", "join_delta", "instance_build", "stacked_build",
+        "distinct_count", "distinct_count_delta", "column_stats", "column_stats_delta",
+    )
+
+    def misses(self) -> int:
+        return sum(self.stats[k] for k in self.MISS_STATS)
+
     def clear(self) -> None:
         self.__init__(max_entries=self.max_entries)
 

@@ -26,8 +26,8 @@ other attributes (bit-identical results are the default contract).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
-import time
 from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
 import jax
@@ -38,7 +38,14 @@ from repro.aqp.size_estimation import EstimationConfig
 from repro.core.catalog import Catalog
 from repro.core.index import IndexEntry, SketchIndex
 from repro.core.maintenance import build_maintainer, repair_sketch
-from repro.core.queries import Query, QueryResult, execute, execute_and_provenance
+from repro.core.queries import (
+    Query,
+    QueryResult,
+    execute,
+    inner_block,
+    provenance_from_inner,
+    result_from_inner,
+)
 from repro.core.ranges import RangeSet, equi_depth_ranges
 from repro.core.sketch import ProvenanceSketch, apply_sketch, capture_sketch, execute_with_sketch
 from repro.core.strategies import (
@@ -49,6 +56,7 @@ from repro.core.strategies import (
 )
 from repro.core.table import Database
 from repro.core.workload import WorkloadLog
+from repro.runtime import trace
 from repro.runtime.guards import hot_path
 from repro.runtime.stable_hash import stable_hash32
 
@@ -82,6 +90,9 @@ class RunInfo:
     # (bit-identical, but without that shard's parallelism).  The per-route
     # detail (which shards, how many retries) lives on ``RouteInfo``.
     degraded: bool = False
+    # The query's spans and counters (``repro.runtime.trace``); the ``t_*``
+    # fields above are read off its spans.
+    trace: Optional[trace.Record] = None
 
     @property
     def t_total(self) -> float:
@@ -279,20 +290,38 @@ class PBDSEngine:
         entry.maintainer = maintainer
         return result.sketch, True
 
+    @contextlib.contextmanager
+    def _serving(self, rec: trace.Record):
+        """``rec`` current under a ``repro.serve`` span; the catalog's misses
+        and evictions meanwhile are counted into it."""
+        stats = self.catalog.stats
+        misses, evictions = self.catalog.misses(), stats["evictions"]
+        try:
+            with trace.recording(rec), trace.span("repro.serve"):
+                yield
+        finally:
+            rec.catalog_misses += self.catalog.misses() - misses
+            rec.catalog_evictions += stats["evictions"] - evictions
+
     @hot_path
-    def _serve_hit(
-        self, q: Query, entry: IndexEntry, t_probe: float
-    ) -> Tuple[QueryResult, RunInfo]:
+    def _serve_hit(self, q: Query, entry: IndexEntry) -> Tuple[QueryResult, ProvenanceSketch, bool]:
         """Serve one index hit over the (repaired-if-stale) sketch instance —
-        the shared hit path of ``run`` and ``run_batch``."""
-        tp = time.perf_counter()
-        sketch, repaired = self._current_sketch(entry)
-        tr = time.perf_counter()
-        res = execute_with_sketch(q, self.db, sketch, catalog=self.catalog)
-        return res, RunInfo(
+        the shared hit path of ``run`` and ``run_batch``, inside the query's
+        ``repro.serve`` span."""
+        with trace.span("repro.repair"):
+            sketch, repaired = self._current_sketch(entry)
+        return execute_with_sketch(q, self.db, sketch, catalog=self.catalog), sketch, repaired
+
+    def _hit_info(self, rec: trace.Record, sketch: ProvenanceSketch,
+                  repaired: bool) -> RunInfo:
+        """A hit's ``RunInfo``: its execution runs from the end of the repair
+        to the end of the serve."""
+        serve, repair = rec.last("repro.serve"), rec.last("repro.repair")
+        return RunInfo(
             reused=True, created=False, attr=sketch.attr, strategy=self.strategy,
-            selectivity=sketch.selectivity, t_probe=t_probe, t_repair=tr - tp,
-            t_execute=time.perf_counter() - tr, repaired=repaired,
+            selectivity=sketch.selectivity, t_probe=rec.last("repro.probe").seconds,
+            t_repair=repair.seconds, t_execute=(serve.end_ns - repair.end_ns) / 1e9,
+            repaired=repaired, trace=rec,
         )
 
     def _worth_it(self, sel: SelectionResult, q: Query,
@@ -315,59 +344,81 @@ class PBDSEngine:
             gain -= self.selection.reuse_weight * self.workload.reach(q, stamp)
         return gain < self.min_selectivity_gain
 
+    def _scan(self, q: Query, provenance: bool = False):
+        """The whole table's inner block (``repro.scan``), then the query's
+        own tail (``repro.finish``): ``(result, provenance mask or None,
+        seconds)``."""
+        with trace.span("repro.scan") as scan:
+            ib = inner_block(self.db, q, self.catalog)
+        with trace.span("repro.finish") as finish:
+            res = result_from_inner(q, ib)
+            prov = (provenance_from_inner(q, ib, self.db[q.table].num_rows)
+                    if provenance else None)
+        return res, prov, scan.seconds + finish.seconds
+
     @hot_path
     def run(self, q: Query) -> Tuple[QueryResult, RunInfo]:
-        t0 = time.perf_counter()
-        entry = self.index.lookup_entry(q) if self.strategy != "NO-PS" else None
-        tp = time.perf_counter()
-        if entry is not None:
-            return self._serve_hit(q, entry, tp - t0)
+        rec = trace.query_record(0)
+        with trace.recording(rec):
+            with self._serving(rec):
+                with trace.span("repro.probe") as probe:
+                    entry = self.index.lookup_entry(q) if self.strategy != "NO-PS" else None
+                if entry is not None:
+                    res, sketch, repaired = self._serve_hit(q, entry)
+            if entry is not None:
+                return res, self._hit_info(rec, sketch, repaired)
 
-        if self.strategy == "NO-PS":
-            res = execute(q, self.db, catalog=self.catalog)
-            return res, RunInfo(False, False, None, "NO-PS", None,
-                                t_execute=time.perf_counter() - tp, t_probe=tp - t0)
+            if self.strategy == "NO-PS":
+                res, _, t_exec = self._scan(q)
+                return res, RunInfo(False, False, None, "NO-PS", None, t_execute=t_exec,
+                                    t_probe=probe.seconds, trace=rec)
 
-        stamp = self.workload.record(q) if self.selection.reuse_aware else None
-        sel = select_attribute(
-            self.strategy, self._select_key(q), q, self.db, self.n_ranges,
-            sample_cache=self.samples, theta=self.theta, cfg=self.cfg,
-            ranges_for=lambda a: self.ranges_for(q.table, a),
-            catalog=self.catalog, aqr_cache=self.aqr,
-            selection=self.selection, selection_cache=self.selection_cache,
-        )
-        t1 = time.perf_counter()
+            stamp = self.workload.record(q) if self.selection.reuse_aware else None
+            with trace.span("repro.select") as select:
+                sel = select_attribute(
+                    self.strategy, self._select_key(q), q, self.db, self.n_ranges,
+                    sample_cache=self.samples, theta=self.theta, cfg=self.cfg,
+                    ranges_for=lambda a: self.ranges_for(q.table, a),
+                    catalog=self.catalog, aqr_cache=self.aqr,
+                    selection=self.selection, selection_cache=self.selection_cache,
+                )
 
-        if not self._worth_it(sel, q, stamp):
-            res = execute(q, self.db, catalog=self.catalog)
-            t2 = time.perf_counter()
-            return res, RunInfo(False, False, None, self.strategy, None,
-                                t_probe=tp - t0, t_select=t1 - tp, t_execute=t2 - t1)
+            if not self._worth_it(sel, q, stamp):
+                res, _, t_exec = self._scan(q)
+                return res, RunInfo(False, False, None, self.strategy, None,
+                                    t_probe=probe.seconds, t_select=select.seconds,
+                                    t_execute=t_exec, trace=rec)
 
-        ranges = self.ranges_for(q.table, sel.attr)
-        self._maybe_cluster(q.table, ranges)
-        tc = time.perf_counter()
-        # Fused path: one inner-block evaluation yields the result AND the
-        # provenance the sketch is captured from (the seed ran it twice).
-        res, prov = execute_and_provenance(q, self.db, catalog=self.catalog)
-        t2 = time.perf_counter()
-        sketch = capture_sketch(q, self.db, ranges, prov=prov, catalog=self.catalog)
-        # Maintenance state rides along from capture: the inner-block products
-        # it needs (group encoding, join layout, bucketization) are all catalog
-        # hits at this point, so the build costs one delta-free counting pass.
-        maintainer = build_maintainer(q, self.db, ranges, self.catalog)
-        self.index.insert(q, sketch, maintainer=maintainer)
-        # Warm the reuse path now, while we are already paying capture cost:
-        # materialize the sketch instance and run the instrumented query once
-        # so its catalog entries (instance, group encoding, join layout) and
-        # kernel compilations exist before the first index hit.
-        execute(q, apply_sketch(sketch, self.db, catalog=self.catalog), catalog=self.catalog)
-        t3 = time.perf_counter()
-        return res, RunInfo(
-            reused=False, created=True, attr=sel.attr, strategy=self.strategy,
-            selectivity=sketch.selectivity, t_probe=tp - t0,
-            t_select=t1 - tp, t_capture=(tc - t1) + (t3 - t2), t_execute=t2 - tc,
-        )
+            with trace.span("repro.capture") as layout:
+                ranges = self.ranges_for(q.table, sel.attr)
+                self._maybe_cluster(q.table, ranges)
+            # Fused path: one inner-block evaluation yields the result AND the
+            # provenance the sketch is captured from (the seed ran it twice).
+            res, prov, t_exec = self._scan(q, provenance=True)
+            with trace.span("repro.capture") as capture:
+                with trace.span("repro.capture.bitmap"):
+                    sketch = capture_sketch(q, self.db, ranges, prov=prov, catalog=self.catalog)
+                # Maintenance state rides along from capture: the inner-block
+                # products it needs (group encoding, join layout, bucketization)
+                # are all catalog hits at this point, so the build costs one
+                # delta-free counting pass.
+                with trace.span("repro.capture.maintainer"):
+                    maintainer = build_maintainer(q, self.db, ranges, self.catalog)
+                    self.index.insert(q, sketch, maintainer=maintainer)
+                # Warm the reuse path now, while we are already paying capture
+                # cost: materialize the sketch instance and run the instrumented
+                # query once so its catalog entries (instance, group encoding,
+                # join layout) and kernel compilations exist before the first
+                # index hit.
+                with trace.span("repro.capture.warm"):
+                    execute(q, apply_sketch(sketch, self.db, catalog=self.catalog),
+                            catalog=self.catalog)
+            return res, RunInfo(
+                reused=False, created=True, attr=sel.attr, strategy=self.strategy,
+                selectivity=sketch.selectivity, t_probe=probe.seconds,
+                t_select=select.seconds, t_capture=layout.seconds + capture.seconds,
+                t_execute=t_exec, trace=rec,
+            )
 
     @hot_path
     def run_batch(self, qs: Sequence[Query]) -> List[Tuple[QueryResult, RunInfo]]:
@@ -403,19 +454,24 @@ class PBDSEngine:
             self.workload.begin_batch(len(qs))
         out: List[Optional[Tuple[QueryResult, RunInfo]]] = [None] * len(qs)
         pending: List[Tuple[int, Query]] = list(enumerate(qs))
-        while pending:
-            misses: List[Tuple[int, Query, float]] = []
-            for i, q in pending:
-                t0 = time.perf_counter()
-                entry = self.index.lookup_entry(q) if self.strategy != "NO-PS" else None
-                tp = time.perf_counter()
-                if entry is None:
-                    misses.append((i, q, tp - t0))
-                    continue
-                out[i] = self._serve_hit(q, entry, tp - t0)
-            if not misses:
-                break
-            served, pending = admit_misses(self, misses)
-            for i, item in served.items():
-                out[i] = item
+        with trace.batch():
+            recs = [trace.query_record(i) for i in range(len(qs))]
+            while pending:
+                misses: List[Tuple[int, Query, trace.Record]] = []
+                for i, q in pending:
+                    with self._serving(recs[i]):
+                        with trace.span("repro.probe"):
+                            entry = (self.index.lookup_entry(q)
+                                     if self.strategy != "NO-PS" else None)
+                        if entry is not None:
+                            res, sketch, repaired = self._serve_hit(q, entry)
+                    if entry is None:
+                        misses.append((i, q, recs[i]))
+                    else:
+                        out[i] = res, self._hit_info(recs[i], sketch, repaired)
+                if not misses:
+                    break
+                served, pending = admit_misses(self, misses)
+                for i, item in served.items():
+                    out[i] = item
         return out  # type: ignore[return-value]

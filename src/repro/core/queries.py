@@ -27,6 +27,7 @@ import numpy as np
 from repro.core.catalog import Catalog, default_catalog
 from repro.core.table import PAD_VALID, ColumnTable, Database
 from repro.runtime.guards import hot_path
+from repro.runtime.trace import span, to_host
 
 Array = jax.Array
 
@@ -276,16 +277,18 @@ def inner_block_arrays(q: Query, flat: ColumnTable, catalog: Catalog):
     shard axis first — either way the aggregation semantics come from here,
     which is what makes routed partials mergeable into bit-identical results.
     """
-    where_mask = (
-        catalog.where_mask(flat, q.where)
-        if q.where is not None
-        else jnp.ones(flat.num_rows, dtype=bool)
-    )
-    if flat.has(PAD_VALID):
-        # Pow2-padded sketch instance: the tail rows exist only to pin the
-        # compiled shape and must contribute nothing (weight 0 everywhere).
-        where_mask = where_mask & flat[PAD_VALID]
-    enc = catalog.groups(flat, q.groupby)
+    with span("repro.where"):
+        where_mask = (
+            catalog.where_mask(flat, q.where)
+            if q.where is not None
+            else jnp.ones(flat.num_rows, dtype=bool)
+        )
+        if flat.has(PAD_VALID):
+            # Pow2-padded sketch instance: the tail rows exist only to pin the
+            # compiled shape and must contribute nothing (weight 0 everywhere).
+            where_mask = where_mask & flat[PAD_VALID]
+    with span("repro.groups"):
+        enc = catalog.groups(flat, q.groupby)
     if q.agg.fn == "count":
         vals = jnp.ones(flat.num_rows, dtype=jnp.float32)
     else:
@@ -300,13 +303,15 @@ def inner_group_partials(
     (already joined) flat table.  Returns ``(enc, where_mask, sums, counts)``.
     """
     enc, where_mask, vals = inner_block_arrays(q, flat, catalog)
-    sums, counts = segment_sums_counts(vals, enc.gid_dev, enc.n_groups, weights=where_mask)
+    with span("repro.aggregate"):
+        sums, counts = segment_sums_counts(vals, enc.gid_dev, enc.n_groups, weights=where_mask)
     return enc, where_mask, sums, counts
 
 
 def _inner_block(db: Database, q: Query, catalog: Optional[Catalog] = None) -> InnerBlock:
     """Evaluate the inner block once; one fused segment pass yields both the
-    aggregate values and group presence."""
+    aggregate values and group presence.  The first copy to the host waits
+    for the aggregation kernel."""
     catalog = catalog or default_catalog()
     if q.join is not None:
         flat, fact_idx = materialize_join(db, q, catalog)
@@ -314,17 +319,17 @@ def _inner_block(db: Database, q: Query, catalog: Optional[Catalog] = None) -> I
         flat, fact_idx = db[q.table], None
     enc, where_mask, sums, counts = inner_group_partials(q, flat, catalog)
     agg = _finalize_aggregate(q.agg.fn, sums, counts)
-    counts_np = np.asarray(counts)
+    counts_np = to_host(counts)  # analyze: waive[SYNC01]: the inner block's result reaches the host here, once per query
     return InnerBlock(
         flat=flat,
         fact_idx=fact_idx,
         gid=enc.gid,
         n_groups=enc.n_groups,
         group_values=enc.group_values,
-        agg_np=np.asarray(agg),
+        agg_np=to_host(agg),  # analyze: waive[SYNC01]: per-group aggregates for the host's HAVING, once per query
         # Groups whose every row fails the WHERE do not appear in the result.
         present=counts_np > 0,
-        where_np=np.asarray(where_mask),
+        where_np=to_host(where_mask),  # analyze: waive[SYNC01]: row mask for provenance derivation, once per query
     )
 
 
@@ -341,6 +346,16 @@ def result_from_group_state(
     *merged* per-shard partial aggregates: given equal per-group values and
     presence, the result matches single-node execution exactly.
     """
+    with span("repro.having"):
+        return _finish_group_state(q, group_values, agg_np, present)
+
+
+def _finish_group_state(
+    q: Query,
+    group_values: Dict[str, np.ndarray],
+    agg_np: np.ndarray,
+    present: np.ndarray,
+) -> QueryResult:
     if q.outer_groupby is None:
         keep = present.copy()
         if q.having is not None:
@@ -371,7 +386,7 @@ def result_from_group_state(
         n_outer,
         q.outer_agg.fn if q.outer_agg else "sum",
     )
-    outer_np = np.asarray(outer_vals)  # analyze: waive[SYNC01]: deliberate merge: outer-query HAVING filters on host, once per query result
+    outer_np = to_host(outer_vals)  # analyze: waive[SYNC01]: deliberate merge: outer-query HAVING filters on host, once per query result
     keep = np.ones(n_outer, dtype=bool)
     if q.outer_having is not None:
         keep &= np.asarray(q.outer_having.mask(outer_np))
@@ -413,7 +428,7 @@ def provenance_group_keep(
                 [group_values[a][inner_idx] for a in q.outer_groupby], axis=1
             )
             uniq, ogid = np.unique(stacked, axis=0, return_inverse=True)
-            outer_vals = np.asarray(  # analyze: waive[SYNC01]: deliberate merge: nested-aggregate outer pass filters on host, once per query result
+            outer_vals = to_host(  # analyze: waive[SYNC01]: deliberate merge: nested-aggregate outer pass filters on host, once per query result
                 segment_aggregate(
                     jnp.asarray(agg_np[inner_idx]),
                     jnp.asarray(ogid.astype(np.int32)),

@@ -56,7 +56,7 @@ import numpy as np
 
 from repro.core.catalog import Catalog
 from repro.core.engine import PBDSEngine, RunInfo
-from repro.runtime import guards
+from repro.runtime import guards, trace
 from repro.runtime.guards import hot_path
 from repro.core.index import IndexEntry
 from repro.core.maintenance import MaintenanceError, SketchMaintainer, maintainer_for
@@ -2034,32 +2034,34 @@ class ShardedEngine:
             self.engine.workload.begin_batch(len(qs))
         out: List[Optional[Tuple[QueryResult, RunInfo]]] = [None] * len(qs)
         pending: List[Tuple[int, Query]] = list(enumerate(qs))
-        while pending:
-            misses: List[Tuple[int, Query, float]] = []
-            hits: Dict[int, List[Tuple[int, Query, IndexEntry, float]]] = {}
-            for i, q in pending:
-                t0 = time.perf_counter()
-                entry = (self.engine.index.lookup_entry(q)
-                         if self.engine.strategy != "NO-PS" else None)
-                tp = time.perf_counter()
-                if entry is None:
-                    misses.append((i, q, tp - t0))
-                elif entry.reg_id in self._registered:
-                    hits.setdefault(entry.reg_id, []).append((i, q, entry, tp - t0))
-                else:
-                    # Hit without routed registration (rare: the registration
-                    # was dropped): single-node serve + re-register, exactly
-                    # like ``run``'s fallback.
-                    out[i] = self.engine.run(q)
-                    self._register_new()
-            if hits:
-                self._serve_hits_batch(list(hits.items()), out)
-            if not misses:
-                break
-            served, pending = admit_misses(self.engine, misses)
-            for i, item in served.items():
-                out[i] = item
-            self._register_new()
+        with trace.batch():
+            recs = [trace.query_record(i) for i in range(len(qs))]
+            while pending:
+                misses: List[Tuple[int, Query, trace.Record]] = []
+                hits: Dict[int, List[Tuple[int, Query, IndexEntry, float]]] = {}
+                for i, q in pending:
+                    with trace.recording(recs[i]), trace.span("repro.probe") as probe:
+                        entry = (self.engine.index.lookup_entry(q)
+                                 if self.engine.strategy != "NO-PS" else None)
+                    if entry is None:
+                        misses.append((i, q, recs[i]))
+                    elif entry.reg_id in self._registered:
+                        hits.setdefault(entry.reg_id, []).append(
+                            (i, q, entry, probe.seconds))
+                    else:
+                        # Hit without routed registration (rare: the
+                        # registration was dropped): single-node serve +
+                        # re-register, exactly like ``run``'s fallback.
+                        out[i] = self.engine.run(q)
+                        self._register_new()
+                if hits:
+                    self._serve_hits_batch(list(hits.items()), out)
+                if not misses:
+                    break
+                served, pending = admit_misses(self.engine, misses)
+                for i, item in served.items():
+                    out[i] = item
+                self._register_new()
         return out  # type: ignore[return-value]
 
     def _serve_hits_batch(

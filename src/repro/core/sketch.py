@@ -28,6 +28,7 @@ from repro.core.queries import (
 )
 from repro.core.ranges import RangeSet
 from repro.core.table import PAD_VALID, ColumnTable, Database
+from repro.runtime.trace import span, to_host
 
 Array = jax.Array
 
@@ -91,9 +92,9 @@ def capture_sketch(
     if use_kernel:
         from repro.kernels import ops as kops
 
-        bits = np.asarray(kops.fragment_bitmap(jnp.asarray(prov), bucket, ranges.n_ranges))  # analyze: waive[SYNC01]: deliberate merge: sketch bits materialize to host once at capture (admission-time)
+        bits = to_host(kops.fragment_bitmap(jnp.asarray(prov), bucket, ranges.n_ranges))  # analyze: waive[SYNC01]: deliberate merge: sketch bits materialize to host once at capture (admission-time)
     else:
-        bits = np.asarray(  # analyze: waive[SYNC01]: deliberate merge: sketch bits materialize to host once at capture (admission-time)
+        bits = to_host(  # analyze: waive[SYNC01]: deliberate merge: sketch bits materialize to host once at capture (admission-time)
             jax.ops.segment_max(
                 jnp.asarray(prov).astype(jnp.int32), bucket, num_segments=ranges.n_ranges
             )
@@ -147,10 +148,10 @@ def capture_sketches_batch(
         if use_kernel:
             from repro.kernels import ops as kops
 
-            bits_b = np.asarray(  # analyze: waive[SYNC01]: deliberate merge: batched capture materializes the whole wave's bits in one transfer
+            bits_b = to_host(  # analyze: waive[SYNC01]: deliberate merge: batched capture materializes the whole wave's bits in one transfer
                 kops.fragment_bitmap_batch(jnp.asarray(stacked), bucket, ranges.n_ranges))
         else:
-            bits_b = np.asarray(  # analyze: waive[SYNC01]: deliberate merge: batched capture materializes the whole wave's bits in one transfer
+            bits_b = to_host(  # analyze: waive[SYNC01]: deliberate merge: batched capture materializes the whole wave's bits in one transfer
                 jax.vmap(
                     lambda p: jax.ops.segment_max(
                         p.astype(jnp.int32), bucket, num_segments=ranges.n_ranges)
@@ -241,14 +242,14 @@ def _build_instance(
         tail_bucket = None
         if lay.tail:
             n = table.num_rows
-            tail_bucket = np.asarray(
+            tail_bucket = to_host(  # analyze: waive[SYNC01]: the tail's bucket ids, once per instance build
                 catalog.bucketize(table, sketch.ranges))[n - lay.tail:]
         inst, rows = table.take_fragments(
             frag_ids, tail_bucket=tail_bucket, return_rows=True)
         return _pad_instance_pow2(inst, rows, catalog)
     catalog.stats["instance_mask"] += 1
     mask = sketch_keep_mask(sketch, table, catalog=catalog)
-    rows = np.nonzero(np.asarray(mask))[0]
+    rows = np.nonzero(to_host(mask))[0]  # analyze: waive[SYNC01]: the kept rows' positions, once per instance build
     return _pad_instance_pow2(table.gather(jnp.asarray(rows)), rows, catalog)
 
 
@@ -262,11 +263,12 @@ def apply_sketch(
     """
     catalog = catalog or default_catalog()
     table = db[sketch.table]
-    instance = catalog.get_instance(sketch, table)
-    if instance is None:
-        instance, rows = _build_instance(sketch, table, catalog)
-        catalog.put_instance(sketch, table, instance, rows=rows)
-    return db.with_table(instance)
+    with span("repro.instance"):
+        instance = catalog.get_instance(sketch, table)
+        if instance is None:
+            instance, rows = _build_instance(sketch, table, catalog)
+            catalog.put_instance(sketch, table, instance, rows=rows)
+        return db.with_table(instance)
 
 
 def execute_with_sketch(

@@ -27,6 +27,7 @@ from repro.core.ranges import RangeSet, equi_depth_ranges
 from repro.core.safety import prefilter_candidates, safe_attributes, stats_prefilter
 from repro.core.sketch import actual_size
 from repro.core.table import Database
+from repro.runtime.trace import span
 
 RANDOM_STRATEGIES = ("RAND-ALL", "RAND-REL-ALL", "RAND-GB", "RAND-PK", "RAND-AGG")
 COST_STRATEGIES = ("CB-OPT", "CB-OPT-REL", "CB-OPT-GB")
@@ -243,21 +244,24 @@ def select_attribute(
     # differ only in thresholds reuse them wholesale.
     sample_cache = sample_cache or SampleCache()
     k_s, k_e = jax.random.split(key)
-    samples = sample_cache.get_or_create(k_s, db[q.table], q.groupby_on_fact(db), theta)
-    if aqr_cache is not None:
-        est, sampled = aqr_cache.get_or_compute(k_e, q, db, samples, theta, cfg)
-        aqr = (est, satisfied_groups(q, est, sampled))
-    else:
-        aqr = approximate_query_result(k_e, q, db, samples, cfg)
+    with span("repro.select.sample"):
+        samples = sample_cache.get_or_create(k_s, db[q.table], q.groupby_on_fact(db), theta)
+    with span("repro.select.aqr"):
+        if aqr_cache is not None:
+            est, sampled = aqr_cache.get_or_compute(k_e, q, db, samples, theta, cfg)
+            aqr = (est, satisfied_groups(q, est, sampled))
+        else:
+            aqr = approximate_query_result(k_e, q, db, samples, cfg)
     # The estimate stage draws from its own key: reusing ``k_e`` would
     # correlate its randomness with the AQR pass's whenever the AQR cache
     # misses.  (With a precomputed ``aqr`` the estimator is deterministic and
     # never consumes the key, so cached and uncached AQR paths still rank
     # candidates identically — pinned by tests/test_selection.py.)
-    estimates: Dict[str, SizeEstimate] = estimate_size_batched(
-        jax.random.fold_in(k_e, 1), q, db, {a: ranges_for(a) for a in cands},
-        samples, cfg, aqr=aqr, catalog=catalog,
-    )
+    with span("repro.select.estimate"):
+        estimates: Dict[str, SizeEstimate] = estimate_size_batched(
+            jax.random.fold_in(k_e, 1), q, db, {a: ranges_for(a) for a in cands},
+            samples, cfg, aqr=aqr, catalog=catalog,
+        )
     # Tuple tie-break, mirrored by the batched path in admission.py: equal
     # estimates resolve by attribute name, never by dict insertion order.
     ranking = tuple(sorted(estimates, key=lambda a: (estimates[a].est_rows, a)))

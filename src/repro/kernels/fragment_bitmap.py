@@ -52,6 +52,7 @@ def fragment_bitmap_batch_pallas(
     n_ranges: int,
     rows_per_tile: int = ROWS_PER_TILE,
     interpret: bool = False,
+    name: str = "fragment_bitmap_batch",
 ) -> jax.Array:
     """bits (bool[B, n_ranges]) from one bucket (int32[n]) and B stacked
     provenance masks (bool[B, n]) — multi-sketch fused capture: one
@@ -83,6 +84,7 @@ def fragment_bitmap_batch_pallas(
         out_specs=pl.BlockSpec((b + b_pad, n_ranges_p), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((b + b_pad, n_ranges_p), jnp.int32),
         interpret=interpret,
+        name=name,
     )(bucket_2d, provs_3d)
     return out[:b, :n_ranges] > 0
 
@@ -98,4 +100,4 @@ def fragment_bitmap_pallas(
     B = 1 batched launch."""
     return fragment_bitmap_batch_pallas(
         bucket, prov[None], n_ranges, rows_per_tile=rows_per_tile,
-        interpret=interpret)[0]
+        interpret=interpret, name="fragment_bitmap")[0]

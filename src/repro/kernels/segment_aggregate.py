@@ -62,6 +62,7 @@ def segment_aggregate_batch_pallas(
     rows_per_tile: int = ROWS_PER_TILE,
     group_block: int = GROUP_BLOCK,
     interpret: bool = False,
+    name: str = "segment_aggregate_batch",
 ):
     """Batched segmented aggregation: B independent segment problems, one grid.
 
@@ -101,6 +102,7 @@ def segment_aggregate_batch_pallas(
         out_specs=[out_spec, out_spec],
         out_shape=[out_shape, out_shape],
         interpret=interpret,
+        name=name,
     )(gid_2d, val_2d, w_2d)
     sums = sums.reshape(b, n_gblocks * group_block, LANE).sum(axis=-1)
     counts = counts.reshape(b, n_gblocks * group_block, LANE).sum(axis=-1)
@@ -121,5 +123,5 @@ def segment_aggregate_pallas(
         values[None], gid[None], n_groups,
         None if weights is None else weights[None],
         rows_per_tile=rows_per_tile, group_block=group_block,
-        interpret=interpret)
+        interpret=interpret, name="segment_aggregate")
     return sums[0], counts[0]

@@ -67,5 +67,6 @@ def sketch_filter_pallas(
         out_specs=pl.BlockSpec((sub, LANE), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_tiles * sub, LANE), jnp.int32),
         interpret=interpret,
+        name="sketch_filter",
     )(bucket_2d, bits_2d)
     return out.reshape(-1)[:n] > 0

@@ -85,7 +85,8 @@ class LaunchCountError(GuardViolation):
 
 
 class CompileWatch:
-    """Live view of backend compilations inside a ``retrace_guard`` block."""
+    """Live view of backend compilations inside a ``retrace_guard`` block:
+    ``events`` holds the compiled function's name for each."""
 
     def __init__(self) -> None:
         self.events: List[str] = []
@@ -111,7 +112,7 @@ def retrace_guard(allowed: Optional[int] = 0, label: str = "") -> Iterator[Compi
 
     def listener(name: str, duration_secs: float, **kw) -> None:
         if name == "/jax/core/compile/backend_compile_duration":
-            watch.events.append(name)
+            watch.events.append(str(kw.get("fun_name", "?")))
 
     monitoring.register_event_duration_secs_listener(listener)
     try:
@@ -121,8 +122,9 @@ def retrace_guard(allowed: Optional[int] = 0, label: str = "") -> Iterator[Compi
     if allowed is not None and watch.compiles > allowed:
         where = f" [{label}]" if label else ""
         raise RetraceError(
-            f"retrace_guard{where}: {watch.compiles} XLA compilation(s), "
-            f"allowed {allowed} — a hot path left its compiled size class")
+            f"retrace_guard{where}: {watch.compiles} XLA compilation(s) "
+            f"({', '.join(watch.events)}), allowed {allowed} — a hot path "
+            f"left its compiled size class")
 
 
 class LaunchWatch:

@@ -109,6 +109,27 @@ def test_consecutive_findings_get_their_own_waivers():
     assert [f.waive_reason for f in findings] == ["first", "second"]
 
 
+def test_to_host_is_a_sync_on_hot_paths():
+    """``trace.to_host`` exists to copy a device value to the host: on a hot
+    path it is flagged whatever its argument, its waiver sits on its line,
+    and the names bound to its result are host values."""
+    src = (
+        "from repro.runtime.guards import hot_path\n"
+        "from repro.runtime.trace import to_host\n"
+        "@hot_path\n"
+        "def serve(counts, agg):\n"
+        "    c = to_host(counts)\n"
+        "    a = to_host(agg)  # analyze: waive[SYNC01]: once per query\n"
+        "    return float(c[0]) + float(a[0])\n"
+        "def cold(counts):\n"
+        "    return to_host(counts)\n"
+    )
+    findings = sorted(analyze_source(src), key=lambda f: f.line)
+    assert [(f.rule, f.line, f.waived) for f in findings] == [
+        ("SYNC01", 5, False), ("SYNC01", 6, True)]
+    assert "to_host" in findings[0].message
+
+
 def test_main_exit_codes(tmp_path, capsys):
     bad = tmp_path / "bad.py"
     bad.write_text("def aqr_cache_key(q):\n    return (q.table,)\n")

@@ -58,6 +58,14 @@ def test_retrace_guard_observe_mode_counts():
     assert watch.compiles == 0
 
 
+def test_retrace_guard_names_the_compiled_functions():
+    x = jnp.ones(24)
+    with pytest.raises(RetraceError, match=r"jit\(_double\)"):
+        with retrace_guard(allowed=0) as watch:
+            _double(x)  # new size class: real backend compile
+    assert watch.events == ["jit(_double)"]
+
+
 def test_retrace_guard_unregisters_when_block_raises():
     """A block that raises leaves no listener behind: later compiles are
     not counted into its watch."""

@@ -164,3 +164,52 @@ def test_chunked_attention_sliding_window():
         causal=True, window=16,
     ).transpose(0, 2, 1, 3)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-3, rtol=2e-3)
+
+
+# -- stable names -------------------------------------------------------------
+
+KERNELS_JSON = (__import__("pathlib").Path(__file__).resolve().parent.parent
+                / "benchmarks" / "chip" / "kernels.json")
+
+
+def _kernel_lowerings():
+    n, b, groups, ranges = 1 << 14, 4, 512, 100
+    f32 = jax.ShapeDtypeStruct((n,), jnp.float32)
+    i32 = jax.ShapeDtypeStruct((n,), jnp.int32)
+    f32_b = jax.ShapeDtypeStruct((b, n), jnp.float32)
+    i32_b = jax.ShapeDtypeStruct((b, n), jnp.int32)
+    bool_n = jax.ShapeDtypeStruct((n,), jnp.bool_)
+    bool_b = jax.ShapeDtypeStruct((b, n), jnp.bool_)
+    bits = jax.ShapeDtypeStruct((ranges,), jnp.bool_)
+    return {
+        "segment_aggregate": (ops._segment_aggregate_jit,
+                              (f32, i32, groups, f32, "pallas")),
+        "segment_aggregate_batch": (ops._segment_aggregate_batch_jit,
+                                    (f32_b, i32_b, groups, f32_b, "pallas")),
+        "fragment_bitmap": (ops._fragment_bitmap_jit, (bool_n, i32, ranges, "pallas")),
+        "fragment_bitmap_batch": (ops._fragment_bitmap_batch_jit,
+                                  (bool_b, i32, ranges, "pallas")),
+        "sketch_filter": (ops._sketch_filter_jit, (i32, bits, "pallas")),
+    }
+
+
+@pytest.mark.parametrize("kernel", ["segment_aggregate", "segment_aggregate_batch",
+                                    "fragment_bitmap", "fragment_bitmap_batch",
+                                    "sketch_filter"])
+def test_kernel_names_are_stable(kernel):
+    """Each wrapper lowers for the TPU to a module named after its jit, with
+    one Mosaic kernel named after the kernel: a profiler trace finds each by
+    name (``benchmarks/chip/kernels.json`` matches the module), so a rename
+    fails here instead of silently emptying a metric."""
+    import json
+    import re
+
+    fn, args = _kernel_lowerings()[kernel]
+    text = fn.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    module = re.search(r"module @(\S+)", text).group(1)
+    assert module == f"jit__{kernel}_jit"
+    assert re.findall(r'kernel_name = "([^"]+)"', text) == [kernel]
+    table = json.loads(KERNELS_JSON.read_text())
+    for name, rule in table.items():
+        assert rule["line"] == "XLA Modules"
+        assert bool(re.search(rule["pattern"], module)) == (name == kernel), (name, module)

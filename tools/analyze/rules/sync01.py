@@ -15,7 +15,9 @@ device-derived; flagged sync forms are
 
 * ``<anything>.item()`` — always a sync;
 * ``float(e)`` / ``int(e)`` where ``e`` mentions a device-derived value;
-* ``np.asarray(e)`` / ``np.array(e)`` likewise.
+* ``np.asarray(e)`` / ``np.array(e)`` likewise;
+* ``to_host(e)`` (``repro.runtime.trace``) — always a sync: it exists to
+  copy a device value to the host.
 """
 from __future__ import annotations
 
@@ -28,6 +30,7 @@ RULE = "SYNC01"
 
 SYNC_BUILTINS = {"float", "int"}
 SYNC_NP = {"np.asarray", "np.array", "numpy.asarray", "numpy.array"}
+SYNC_TO_HOST = {"to_host", "trace.to_host"}
 
 
 def _device_rooted_call(call: ast.Call) -> bool:
@@ -40,7 +43,8 @@ def _is_host_copy(value: ast.AST) -> bool:
     the transfer is flagged at that line; downstream float()/int() on the
     bound names are free."""
     if isinstance(value, ast.Call):
-        return call_name(value) in SYNC_NP
+        name = call_name(value)
+        return name in SYNC_NP or name in SYNC_TO_HOST
     if isinstance(value, (ast.Tuple, ast.List)) and value.elts:
         return all(_is_host_copy(e) for e in value.elts)
     return False
@@ -113,6 +117,11 @@ def check(module: ModuleInfo, ctx: Context) -> List[Finding]:
                 out.append(Finding(
                     RULE, module.path, sub.lineno,
                     "hot-path .item() forces a device->host sync"))
+                continue
+            if name in SYNC_TO_HOST:
+                out.append(Finding(
+                    RULE, module.path, sub.lineno,
+                    "hot-path to_host() copies a device value to the host"))
                 continue
             if name in SYNC_BUILTINS and len(sub.args) == 1 \
                     and not _is_static_metadata(sub.args[0]) \
