@@ -189,14 +189,16 @@ def segment_sums_counts(
     Dispatches the Pallas one-hot MXU kernel on TPU and the segment-sum
     reference path elsewhere (see ``repro.kernels.ops``).  Row and group
     dimensions are padded to powers of two so the jitted kernel wrapper
-    compiles once per size class instead of once per query shape.
+    compiles once per size class instead of once per query shape.  The
+    mask goes to the kernel in its own dtype: its cast to float32 then runs
+    inside the kernel's jit, not as a dispatch of its own before it.
     """
     from repro.kernels import ops as kops
 
     n = int(values.shape[0])
     n_pad = _next_pow2(max(n, 1))
     g_pad = _next_pow2(max(n_groups, 1))
-    w = jnp.ones(n, dtype=jnp.float32) if weights is None else weights.astype(jnp.float32)
+    w = jnp.ones(n, dtype=bool) if weights is None else weights
     if n_pad != n:
         # Padded rows carry weight 0 into group 0: they contribute nothing.
         values = jnp.pad(values.astype(jnp.float32), (0, n_pad - n))

@@ -114,6 +114,125 @@ def test_segment_aggregate_exact_above_bf16_range(n, g):
     np.testing.assert_array_equal(np.asarray(sb[0], np.float64), want)
 
 
+# Per-group sums of |v * w| up to 2^24 keep every partial sum of every bf16
+# part an integer within float32's exact range (the kernel's exactness
+# envelope): at most 15 rows a group of values up to 2^20.
+ROWS_PER_GROUP = 15
+ENVELOPE = 2 ** 24
+
+
+def _integral_segments(g, n_pad_rows, rng):
+    """Signed integral values in [257, 2^20] with at most 15 rows a group,
+    then ``n_pad_rows`` padded rows (gid -1, weight 0, a large value)."""
+    per = min(ROWS_PER_GROUP, max(1, 12_000 // g))
+    gid = rng.permutation(np.repeat(np.arange(g, dtype=np.int32), per))
+    n = gid.shape[0]
+    vals = (rng.integers(257, 2 ** 20 + 1, n) * rng.choice([-1, 1], n)).astype(np.float32)
+    w = rng.random(n) < 0.75
+    gid = np.concatenate([gid, np.full(n_pad_rows, -1, np.int32)])
+    vals = np.concatenate([vals, np.full(n_pad_rows, 2.0 ** 20, np.float32)])
+    w = np.concatenate([w, np.zeros(n_pad_rows, bool)])
+    return gid, vals, w
+
+
+@pytest.mark.parametrize("weights", ["bool", "float"])
+@pytest.mark.parametrize("g", [16, 128, 129, 700, 16384, 16385, 40000])
+def test_segment_aggregate_two_level_exact(g, weights):
+    """Group counts on both sides of the (hi, lo) split and of the 16,384
+    groups of one group block, signed integral values above bf16's exact
+    range, padded rows with gid -1 and weight 0: the kernel equals a float64
+    ``np.bincount`` bit for bit, whether the 0/1 mask is boolean or float."""
+    rng = np.random.default_rng(g)
+    gid, vals, w = _integral_segments(g, 37, rng)
+    real = gid >= 0
+    want = np.bincount(gid[real], weights=vals[real].astype(np.float64) * w[real],
+                       minlength=g)
+    want_c = np.bincount(gid[real], weights=w[real].astype(np.float64), minlength=g)
+    assert np.bincount(gid[real], weights=np.abs(vals[real]) * w[real]).max() <= ENVELOPE
+    wd = jnp.asarray(w if weights == "bool" else w.astype(np.float32))
+    s, c = ops.segment_aggregate(jnp.asarray(vals), jnp.asarray(gid), g, wd,
+                                 backend="interpret")
+    np.testing.assert_array_equal(np.asarray(s, np.float64), want)
+    np.testing.assert_array_equal(np.asarray(c, np.float64), want_c)
+
+
+@pytest.mark.parametrize("g", [16, 129, 700, 16385, 40000])
+def test_segment_aggregate_batch_two_level_exact(g):
+    """B > 1 on signed integral values above 256 with padded rows: the
+    batched kernel equals the batched reference and the unbatched kernel on
+    each problem, bit for bit."""
+    rng = np.random.default_rng(1000 + g)
+    rows = [_integral_segments(g, 5 + 11 * i, rng) for i in range(3)]
+    n = min(r[0].shape[0] for r in rows)
+    gid, vals, w = (jnp.asarray(np.stack([r[k][:n] for r in rows])) for k in range(3))
+    sb, cb = ops.segment_aggregate_batch(vals, gid, g, w, backend="interpret")
+    sr, cr = ref.segment_aggregate_batch_ref(vals, gid, g, w)
+    np.testing.assert_array_equal(np.asarray(sb), np.asarray(sr))
+    np.testing.assert_array_equal(np.asarray(cb), np.asarray(cr))
+    for i in range(3):
+        s1, c1 = ops.segment_aggregate(vals[i], gid[i], g, w[i], backend="interpret")
+        np.testing.assert_array_equal(np.asarray(sb[i]), np.asarray(s1))
+        np.testing.assert_array_equal(np.asarray(cb[i]), np.asarray(c1))
+
+
+def _sum_to(total, rng):
+    """Integers in [257, 2^20], with one value of any size up to 2^20 last,
+    that add up to ``total``."""
+    out = []
+    while total > 2 ** 20:
+        out.append(int(rng.integers(257, 2 ** 20 + 1)))
+        total -= out[-1]
+    return out + [total]
+
+
+@pytest.mark.parametrize("g", [16, 700, 40000])
+def test_segment_aggregate_exact_at_the_envelope(g):
+    """Groups whose sum of |v| is 2^24 - 1 or 2^24 exactly, positive,
+    negative and of mixed sign (the mixed group's own sum is small), in the
+    unbatched and the batched kernel: still bit-identical to float64."""
+    rng = np.random.default_rng(7 + g)
+    groups = {0: _sum_to(2 ** 24 - 1, rng),
+              g - 1: [-x for x in _sum_to(2 ** 24, rng)]}
+    half = _sum_to(2 ** 23, rng)
+    groups[g // 2] = half + [-x for x in _sum_to(2 ** 23, rng)]
+    gid = np.concatenate([np.full(len(v), k, np.int32) for k, v in groups.items()])
+    vals = np.concatenate([np.asarray(v, np.float32) for v in groups.values()])
+    order = rng.permutation(gid.shape[0])
+    gid, vals = gid[order], vals[order]
+    assert all(np.sum(np.abs(np.asarray(v, np.float64))) <= 2 ** 24
+               for v in groups.values())
+    want = np.bincount(gid, weights=vals.astype(np.float64), minlength=g)
+    s, c = ops.segment_aggregate(jnp.asarray(vals), jnp.asarray(gid), g,
+                                 backend="interpret")
+    np.testing.assert_array_equal(np.asarray(s, np.float64), want)
+    np.testing.assert_array_equal(np.asarray(c, np.float64),
+                                  np.bincount(gid, minlength=g))
+    sb, _ = ops.segment_aggregate_batch(
+        jnp.asarray(np.stack([vals, vals[::-1]])), jnp.asarray(np.stack([gid, gid[::-1]])),
+        g, backend="interpret")
+    np.testing.assert_array_equal(np.asarray(sb, np.float64), np.stack([want, want]))
+
+
+@pytest.mark.parametrize("bad", [np.inf, -np.inf, np.nan])
+def test_segment_aggregate_dropped_rows_add_nothing(bad):
+    """A row the mask drops adds 0 to every group, whatever its value: an
+    inf or NaN there spoils neither its group nor the groups of its row of
+    128."""
+    g, n = 300, 2000
+    rng = np.random.default_rng(11)
+    gid = rng.integers(0, g, n).astype(np.int32)
+    vals = rng.integers(257, 4097, n).astype(np.float32)
+    w = rng.random(n) < 0.75
+    vals[~w] = bad
+    want = np.bincount(gid[w], weights=vals[w].astype(np.float64), minlength=g)
+    for wd in (w, w.astype(np.float32)):
+        s, c = ops.segment_aggregate(jnp.asarray(vals), jnp.asarray(gid), g,
+                                     jnp.asarray(wd), backend="interpret")
+        np.testing.assert_array_equal(np.asarray(s, np.float64), want)
+        np.testing.assert_array_equal(np.asarray(c, np.float64),
+                                      np.bincount(gid[w], minlength=g))
+
+
 @pytest.mark.parametrize("s,t", [(64, 64), (96, 96), (1, 96)])
 @pytest.mark.parametrize("causal,window", [(True, 0), (True, 32), (False, 0)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -3,7 +3,7 @@
 The TPU compiler is installed beside the CPU backend, so the Pallas kernels
 and the 4-device ``shard_map`` launch compile here for a ``v5e:2x2``
 topology at deployment widths: 8M rows (a 6.7M-row table padded to its
-power-of-two size class), 512 and 128 groups, 1,000 ranges, batch 16.
+power-of-two size class), 128 to 65,536 groups, 1,000 ranges, batch 16.
 Interpret mode cannot see what Mosaic refuses (layouts, tiling, VMEM over
 the scoped limit); these compiles can.  Nothing runs, so results and times
 come only from the chip.
@@ -75,7 +75,7 @@ def _assert_fits(compiled):
     assert total < HBM_BYTES, total
 
 
-@pytest.mark.parametrize("n_groups", [512, 128])
+@pytest.mark.parametrize("n_groups", [512, 128, 8192, 16384, 65536])
 def test_segment_aggregate_compiles(one_chip, n_groups):
     f32 = jax.ShapeDtypeStruct((ROWS,), jnp.float32, sharding=one_chip)
     i32 = jax.ShapeDtypeStruct((ROWS,), jnp.int32, sharding=one_chip)
@@ -84,7 +84,7 @@ def test_segment_aggregate_compiles(one_chip, n_groups):
     _assert_fits(compiled)
 
 
-@pytest.mark.parametrize("n_groups", [512, 128])
+@pytest.mark.parametrize("n_groups", [512, 128, 8192, 16384, 65536])
 def test_segment_aggregate_batch_compiles(one_chip, n_groups):
     shape = (BATCH, ROWS // BATCH)
     f32 = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
