@@ -34,7 +34,7 @@ class Answer:
 def readings(cell: harness.Cell, seed: int, seconds: float) -> dict:
     import ml_dtypes
 
-    gen = datagen.generate(cell.config, seed)
+    gen = datagen.generate(cell.config, seed, root=cell.root)
     tables = {k: cols for k, (cols, _) in gen.items()}
     stream = traffic.build(cell.traffic, tables, seed, seconds)
     low = [reference.exact_groups(t, tables, value_dtype=ml_dtypes.bfloat16)

@@ -2,10 +2,11 @@
 
 Set-up (counted in ``setup_s``, from process start to the window's start):
 generate the configuration's tables from the seed, load them onto the
-device, build the seeded query stream, capture the mix's sketches
-(``PBDSEngine.run_batch`` over one query per template at the set-up
-quantile, which must create a sketch for each), and warm up every template
-on the path the window takes.
+device, build the seeded query stream, start the engine with the cell's
+settings (``Cell.engine``), capture the mix's sketches where the traffic
+file asks for captures (``PBDSEngine.run_batch`` over one query per
+template at the set-up quantile, which must create a sketch for each), and
+warm up every template on the path the window takes.
 
 Window: arrivals are due at the stream's fixed offsets.  At each step the
 harness takes every query that is due and unanswered and passes them all in
@@ -22,7 +23,6 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import gc
-import importlib.util
 import json
 import os
 import shutil
@@ -33,6 +33,8 @@ import traceback
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+
+from benchmarks.chip import load_module
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
@@ -75,13 +77,14 @@ class Cell:
     per_layer: List[dict]
     root: str
 
+    @property
+    def engine(self) -> dict:
+        """The engine's settings: the configuration's, with the traffic
+        file's ``engine`` laid over them."""
+        return {**self.config["engine"], **self.traffic.get("engine", {})}
+
     def metric_module(self, name: str):
-        path = os.path.join(self.root, "benchmarks", "chip", "metrics", f"{name}.py")
-        spec = importlib.util.spec_from_file_location(
-            "chipbench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
+        return load_module(self.root, "metrics", name)
 
 
 def _applies(metric: dict, cell: str) -> bool:
@@ -124,6 +127,7 @@ class RunRecord:
     live_rows: Dict[str, int]  # rows of each table
     groups: List[int]  # exact group count of each template
     device_kind: str
+    catalog_misses: Optional[int] = None  # the engine catalog's, inside the window
 
 
 @dataclasses.dataclass
@@ -262,7 +266,7 @@ def prepare(cell: Cell, seed: int, seconds: float, rate: Optional[float],
     import jax
 
     from benchmarks.chip import datagen, traffic
-    from repro.core import Aggregate, Database, Having, PBDSEngine, Query
+    from repro.core import Aggregate, Database, Having, JoinSpec, PBDSEngine, Query
     from repro.core.table import from_numpy
 
     split = {}
@@ -271,7 +275,7 @@ def prepare(cell: Cell, seed: int, seconds: float, rate: Optional[float],
     if rehearse:
         scale = min(1.0, REHEARSE_ROWS / max(cell.config["generator"]["rows"].values()))
         rate = min(rate or cell.traffic["rate_qps"], REHEARSE_RATE)
-    gen = datagen.generate(cell.config, seed, scale)
+    gen = datagen.generate(cell.config, seed, scale, cell.root)
     tables_np = {name: cols for name, (cols, _) in gen.items()}
     split["generate_s"] = time.perf_counter() - t
 
@@ -287,9 +291,10 @@ def prepare(cell: Cell, seed: int, seconds: float, rate: Optional[float],
     def query(j: int, thr: float) -> Query:
         tp = stream.templates[j]
         return Query(tp.table, tp.groupby, Aggregate(tp.agg_fn, tp.agg_attr),
-                     having=Having(stream.op, thr))
+                     having=Having(stream.op, thr),
+                     join=None if tp.join is None else JoinSpec(*tp.join))
 
-    engine = PBDSEngine(db, **cell.config["engine"])
+    engine = PBDSEngine(db, **cell.engine)
 
     checked = []
     t = time.perf_counter()
@@ -376,6 +381,7 @@ def _run(cell: Cell, args, devices, t_top: float, age_at_top: float) -> int:
     gc.collect()
     gc.freeze()
     setup_s = age_at_top + (time.perf_counter() - t_top)
+    misses_before = engine.catalog.misses()
     monitoring.register_event_duration_secs_listener(on_event)
     try:
         win = run_window(engine, queries, stream.due, args.seconds, annotate)
@@ -384,6 +390,7 @@ def _run(cell: Cell, args, devices, t_top: float, age_at_top: float) -> int:
         if args.trace:
             jax.profiler.stop_trace()
     gc.unfreeze()
+    catalog_misses = engine.catalog.misses() - misses_before
 
     peak = 0
     for d in devices[:cell.chips]:
@@ -430,7 +437,8 @@ def _run(cell: Cell, args, devices, t_top: float, age_at_top: float) -> int:
                            window_infos=win.infos, batches=win.batches,
                            compiles=len(compiles), trace=summary,
                            live_rows=live_rows, groups=groups,
-                           device_kind=devices[0].device_kind)
+                           device_kind=devices[0].device_kind,
+                           catalog_misses=catalog_misses)
         for m in cell.per_layer:
             value = cell.metric_module(m["name"]).read(record)
             if value is not None:
@@ -455,7 +463,8 @@ def _run(cell: Cell, args, devices, t_top: float, age_at_top: float) -> int:
           + f" setup_s={setup_s:.3f}", file=log)
     print(f"window offered={n} answered={n - unanswered} batches={len(sizes)} "
           f"max_batch={max(sizes, default=0)} window_end_s={win.end:.3f} "
-          f"compiles={len(compiles)} errors={len(win.errors)} "
+          f"compiles={len(compiles)} catalog_misses={catalog_misses} "
+          f"errors={len(win.errors)} "
           f"lateness_ms_mean={late.mean() if len(late) else 0.0:.4f} "
           f"lateness_ms_max={late.max() if len(late) else 0.0:.4f}", file=log)
     hits = sum(1 for i in win.infos if i is not None and i.reused)

@@ -4,7 +4,9 @@ Shares no code with the engine.  Group keys come from ``np.unique`` and
 aggregates from float64 ``np.bincount`` (the approach of the repository's
 ``chip_smoke.py`` reference, which this copies).  ``value_dtype`` rounds the
 aggregated column first: the lower-precision control
-(``ml_dtypes.bfloat16``) uses it, the reference itself never does.
+(``ml_dtypes.bfloat16``) uses it, the reference itself never does.  A
+join template's inner block is an inner equi-join made here with
+``searchsorted`` on the unique right key (``joined``).
 
 ``compare`` holds one engine answer against the exact groups of its
 template: group membership (HAVING) and each returned value.  Groups whose
@@ -33,10 +35,34 @@ class ExactGroups:
         return {k: i for i, k in enumerate(self.keys)}
 
 
+def joined(fact: Dict[str, np.ndarray], right: Dict[str, np.ndarray],
+           left_key: str, right_key: str) -> Dict[str, np.ndarray]:
+    """Inner equi-join ``fact.left_key = right.right_key``: each fact row
+    with its one partner's columns; fact rows with no partner are dropped.
+    The right key must be unique and no column name may be on both sides."""
+    shared = sorted(set(fact) & set(right))
+    if shared:
+        raise ValueError(f"columns on both sides of the join: {shared}")
+    rk = right[right_key]
+    if len(np.unique(rk)) != len(rk):
+        raise ValueError(f"the join's right key {right_key!r} is not unique")
+    order = np.argsort(rk, kind="stable")
+    pos = np.minimum(np.searchsorted(rk[order], fact[left_key]), len(rk) - 1)
+    partner = order[pos]
+    hit = np.nonzero(rk[partner] == fact[left_key])[0]
+    out = {a: v[hit] for a, v in fact.items()}
+    out.update({a: v[partner[hit]] for a, v in right.items()})
+    return out
+
+
 def exact_groups(template, tables: Dict[str, Dict[str, np.ndarray]],
                  value_dtype=None) -> ExactGroups:
-    """Aggregate of every present group of ``template``'s inner block."""
+    """Aggregate of every present group of ``template``'s inner block: its
+    table, joined first where the template has a ``join``."""
     cols = tables[template.table]
+    if template.join is not None:
+        j = template.join
+        cols = joined(cols, tables[j.right], j.left_key, j.right_key)
     n = len(cols[template.groupby[0]])
     codes, uniques = [], []
     for a in template.groupby:

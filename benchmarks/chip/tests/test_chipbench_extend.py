@@ -1,11 +1,11 @@
-"""A new configuration, traffic mix and per-layer metric are files plus
-``BENCHMARK.json`` entries: the harness finds them by name, with no edit to
-any file it already has."""
+"""A new configuration, generator, traffic mix and per-layer metric are files
+plus ``BENCHMARK.json`` entries: the harness finds them by name, with no edit
+to any file it already has."""
 import json
 import os
-import shutil
 
-from chipbench_util import ROOT, rehearse
+from chipbench_util import (ROOT, TOY_CELLS, add_toy_join_cells, copy_benchmark,
+                            rehearse)
 
 NEW_METRIC = '''"""Share of the window's answered queries served as index hits."""
 LAYER = "index probe (core/index.py)"
@@ -21,11 +21,7 @@ def read(run):
 
 
 def test_new_files_and_entries_add_a_cell(capsys, tmp_path):
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    for p in bench["paths"]:
-        shutil.copytree(os.path.join(ROOT, p), tmp_path / p)
-    os.symlink(os.path.join(ROOT, "src"), tmp_path / "src")
+    bench = copy_benchmark(tmp_path)
     chip = tmp_path / "benchmarks" / "chip"
     before = {p: p.read_bytes() for p in chip.rglob("*") if p.is_file()}
 
@@ -55,6 +51,29 @@ def test_new_files_and_entries_add_a_cell(capsys, tmp_path):
     rc, line = rehearse(capsys, "crimes-1m-two-panels", trace=1, root=str(tmp_path))
     # The other per-layer metrics list their cells; only the new one applies.
     assert rc == 0 and line["metrics"] == {"hit_share": {"value": 1.0, "unit": "share"}}
+    assert all(p.read_bytes() == b for p, b in before.items())
+
+
+def test_new_files_add_a_join_configuration_and_its_nops_twin(capsys, tmp_path):
+    """A two-table generator, its configuration, a join template and the same
+    traffic with the engine's ``NO-PS`` override: the join cell answers from
+    sketches, its twin from scans, and both are correct."""
+    bench = copy_benchmark(tmp_path)
+    chip = tmp_path / "benchmarks" / "chip"
+    before = {p: p.read_bytes() for p in chip.rglob("*") if p.is_file()}
+    (chip / "metrics" / "hit_share.py").write_text(NEW_METRIC)
+    bench["per_layer"].append({"name": "hit_share", "unit": "share", "better": "higher",
+                               "source": "program_counter", "layer": "index probe (core/index.py)",
+                               "moves": "p50_ms", "workloads": list(TOY_CELLS)})
+    add_toy_join_cells(tmp_path, bench)
+
+    for cell, hit_share in zip(TOY_CELLS, (1.0, 0.0)):
+        rc, line = rehearse(capsys, cell, seed=2**31 + 5, root=str(tmp_path))
+        assert rc == 0 and line["correct"] is True and line["attempted"] == 4
+        assert set(line["metrics"]) == {m["name"] for m in bench["end_to_end"]}
+        rc, line = rehearse(capsys, cell, seed=6, trace=1, root=str(tmp_path))
+        assert rc == 0 and line["correct"] is True
+        assert line["metrics"] == {"hit_share": {"value": hit_share, "unit": "share"}}
     assert all(p.read_bytes() == b for p, b in before.items())
 
 

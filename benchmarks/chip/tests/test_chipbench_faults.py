@@ -6,7 +6,7 @@ import dataclasses
 import numpy as np
 import pytest
 
-from chipbench_util import BENCH, rehearse
+from chipbench_util import BENCH, TOY_CELLS, add_toy_join_cells, copy_benchmark, rehearse
 
 CELL = BENCH["workloads"][0]["name"]
 
@@ -96,3 +96,24 @@ def test_wrong_hit_is_not_correct(capsys, monkeypatch):
     rc, line = rehearse(capsys, CELL, seed=23, seconds=2)
     assert rc == 0 and line["correct"] is False
     assert line["checks"]["wrong_groups"]["value"] > 0
+
+
+@pytest.mark.parametrize("cell", TOY_CELLS)
+def test_unmatched_fact_rows_kept_is_not_correct(capsys, monkeypatch, tmp_path, cell):
+    """The join keeps the fact rows that have no partner, pairing each with
+    the dimension's first row, in a join cell and its NO-PS twin."""
+    from repro.core import catalog
+
+    bench = copy_benchmark(tmp_path)
+    add_toy_join_cells(tmp_path, bench)
+    orig = catalog.join_rows
+
+    def keep_unmatched(fact_cols, right, left_key, right_key):
+        lk, rk = np.asarray(fact_cols[left_key]), np.asarray(right[right_key])
+        paired = dict(fact_cols, **{left_key: np.where(np.isin(lk, rk), lk, rk[0])})
+        return orig(paired, right, left_key, right_key)
+
+    monkeypatch.setattr(catalog, "join_rows", keep_unmatched)
+    rc, line = rehearse(capsys, cell, seed=24, root=str(tmp_path))
+    assert rc == 0 and line["correct"] is False
+

@@ -1,15 +1,11 @@
 """The readers of the program's own spans and counters (``RunInfo.trace``) on
 hand-built records, ``None`` from a program whose ``RunInfo`` carries no
-record, and their values in a traced rehearsal of a copy of the benchmark that
-lists them."""
+record, and their values in a traced rehearsal of the cell that lists them."""
 import dataclasses
-import json
-import os
-import shutil
 
 import pytest
 
-from chipbench_util import ROOT, rehearse
+from chipbench_util import BENCH, ROOT, rehearse
 
 from benchmarks.chip import harness
 from repro.core.engine import RunInfo
@@ -114,25 +110,18 @@ def test_reader_without_a_record_reads_nothing(name):
     assert _read(name, _run([], [])) is None
 
 
-def test_new_readers_are_listed_for_the_cell(capsys, tmp_path):
-    """Listed for the cell, as ``ENTRIES`` gives them, the readers report in a
-    traced rehearsal, each with the layer, unit, source and end-to-end metric
-    of its entry; the checkout's own files are left as they were."""
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    for p in bench["paths"]:
-        shutil.copytree(os.path.join(ROOT, p), tmp_path / p)
-    os.symlink(os.path.join(ROOT, "src"), tmp_path / "src")
-    bench["per_layer"] += ENTRIES
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
-
-    cell = harness.load_cell(str(tmp_path), "crimes-synth-qagh-hits")
-    assert {m["name"] for m in cell.per_layer} >= set(NEW)
+def test_new_readers_are_listed_for_the_cell(capsys):
+    """``BENCHMARK.json`` lists the readers for the cell as ``ENTRIES`` gives
+    them, and they report in a traced rehearsal, each with the layer, unit,
+    source and end-to-end metric of its entry."""
+    cell = _cell()
+    listed = {m["name"]: m for m in BENCH["per_layer"]}
+    assert [listed[m["name"]] for m in ENTRIES] == ENTRIES
     for m in ENTRIES:
         mod = cell.metric_module(m["name"])
         assert (mod.LAYER, mod.UNIT, mod.SOURCE, mod.MOVES) == (
             m["layer"], m["unit"], m["source"], m["moves"]), m["name"]
-    rc, line = rehearse(capsys, "crimes-synth-qagh-hits", seed=5, trace=1, root=str(tmp_path))
+    rc, line = rehearse(capsys, "crimes-synth-qagh-hits", seed=5, trace=1)
     assert rc == 0 and line["correct"] is True
     for m in ENTRIES:
         assert line["metrics"][m["name"]]["unit"] == m["unit"]

@@ -1,6 +1,8 @@
-"""The reference and its comparison catch wrong answers; the lower-precision
-control fails the limits; the traffic generator gives every seed the same
-work; the roofline's bytes are right on stated shapes."""
+"""The reference and its comparison catch wrong answers; the join follows
+the engine's semantics; the lower-precision control fails the limits; the
+crimes data is pinned; the traffic generator gives every seed the same work;
+the roofline's bytes are right on stated shapes."""
+import hashlib
 import json
 import os
 
@@ -41,6 +43,73 @@ def test_reference_matches_a_loop(crimes):
         key = tuple(float(v) for v in row[:-1])
         want[key] = want.get(key, 0) + int(row[-1])
     assert dict(zip(ex.keys, ex.values.tolist())) == want
+
+
+def _join_template(groupby, agg):
+    return traffic.Template("J", "fact", tuple(groupby), agg[0], agg[1],
+                            traffic.Join("dim", "f_key", "d_key"))
+
+
+@pytest.fixture
+def star():
+    rng = np.random.default_rng(8)
+    fact = {"f_key": rng.integers(0, 30, 500).astype(np.int32),  # keys 25..29: no partner
+            "f_day": rng.integers(1, 5, 500).astype(np.int32),
+            "f_amount": rng.integers(1, 50, 500).astype(np.int32)}
+    dim = {"d_key": rng.permutation(25).astype(np.int32),
+           "d_region": rng.integers(1, 4, 25).astype(np.int32)}
+    return {"fact": fact, "dim": dim}
+
+
+@pytest.mark.parametrize("groupby,agg", [
+    (["d_region"], ["sum", "f_amount"]),
+    (["f_day", "d_region"], ["avg", "f_amount"]),
+    (["d_region"], ["count", None]),
+])
+def test_join_reference_matches_a_dict_join(star, groupby, agg):
+    """Inner join: each fact row with its one partner, unmatched fact rows
+    dropped; group-by attributes from either table."""
+    partner = {int(k): i for i, k in enumerate(star["dim"]["d_key"])}
+    sums, counts = {}, {}
+    fact, dim = star["fact"], star["dim"]
+    for r in range(len(fact["f_key"])):
+        i = partner.get(int(fact["f_key"][r]))
+        if i is None:
+            continue
+        row = {**{a: v[r] for a, v in fact.items()}, **{a: v[i] for a, v in dim.items()}}
+        key = tuple(float(row[a]) for a in groupby)
+        sums[key] = sums.get(key, 0) + (int(row[agg[1]]) if agg[1] else 1)
+        counts[key] = counts.get(key, 0) + 1
+    want = {k: {"sum": sums[k], "count": counts[k], "avg": sums[k] / counts[k]}[agg[0]]
+            for k in sums}
+    assert sum(counts.values()) < len(fact["f_key"])  # some rows had no partner
+    ex = reference.exact_groups(_join_template(groupby, agg), star)
+    assert dict(zip(ex.keys, ex.values.tolist())) == pytest.approx(want)
+
+
+def test_join_reference_refuses_what_the_engine_would_rename_or_repeat(star):
+    t = _join_template(["d_region"], ["sum", "f_amount"])
+    clash = dict(star, dim=dict(star["dim"], f_day=star["dim"]["d_region"]))
+    with pytest.raises(ValueError, match="both sides"):
+        reference.exact_groups(t, clash)
+    twice = dict(star["dim"], d_key=star["dim"]["d_key"] % 20)
+    with pytest.raises(ValueError, match="not unique"):
+        reference.exact_groups(t, dict(star, dim=twice))
+
+
+def test_crimes_data_is_pinned():
+    """The crimes generator gives the bytes it gave before it moved into
+    ``generators/crimes.py``: same draws, same order, same dtypes."""
+    gen = datagen.generate(_config("crimes-synth-6.7m"), seed=3, scale=0.01)
+    assert list(gen) == ["crimes"]
+    cols, pk = gen["crimes"]
+    assert pk == ("beat", "year", "month") and len(cols["pid"]) == 67_000
+    h = hashlib.sha256()
+    for name in sorted(cols):
+        h.update(name.encode())
+        h.update(str(cols[name].dtype).encode())
+        h.update(cols[name].tobytes())
+    assert h.hexdigest() == "96f13326631b98c5f1ded77a723407d2e56367e60a49df3e1858715615aa0964"
 
 
 def test_compare_catches_planted_wrong_answers(crimes):

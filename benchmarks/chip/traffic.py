@@ -2,10 +2,13 @@
 open-loop stream of aggregate queries with a HAVING threshold.
 
 A traffic file (``traffic/<mix>.json``) names query templates over the
-configuration's tables, their popularity (Zipf over the listed order; s = 0 is uniform), the
-HAVING threshold as a quantile range of each template's group aggregates,
-the quantile the set-up captures at (``null``: no captures), and the offered
-rate.
+configuration's tables (a template may join its table to another one:
+``"join": {"right", "left_key", "right_key"}``), their popularity (Zipf over
+the listed order; s = 0 is uniform), the HAVING threshold as a quantile
+range of each template's group aggregates, the quantile the set-up captures
+at (``null``: no captures), and the offered rate.  An ``"engine"`` object,
+where present, is laid over the configuration's engine settings (the
+harness's ``Cell.engine``).
 
 Every seed gets the same work: the number of queries per template
 (largest remainder of the Zipf shares), the sequence in which the templates
@@ -24,11 +27,20 @@ from __future__ import annotations
 
 import dataclasses
 import json
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
 from benchmarks.chip import reference
+
+
+class Join(NamedTuple):
+    """Inner equi-join of the template's table with ``right`` on
+    ``left_key = right_key``; the right key is unique."""
+
+    right: str
+    left_key: str
+    right_key: str
 
 
 @dataclasses.dataclass(frozen=True)
@@ -38,6 +50,7 @@ class Template:
     groupby: Tuple[str, ...]
     agg_fn: str
     agg_attr: Optional[str]
+    join: Optional[Join] = None
 
 
 @dataclasses.dataclass
@@ -60,8 +73,9 @@ def load(path: str) -> dict:
 def templates(traffic: dict) -> List[Template]:
     out = []
     for t in traffic["templates"]:
+        join = Join(**t["join"]) if "join" in t else None
         out.append(Template(name=t["name"], table=t["table"], groupby=tuple(t["groupby"]),
-                            agg_fn=t["agg"][0], agg_attr=t["agg"][1]))
+                            agg_fn=t["agg"][0], agg_attr=t["agg"][1], join=join))
     return out
 
 
