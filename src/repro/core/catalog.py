@@ -13,7 +13,8 @@ whole relation.  The ``Catalog`` is the DBMS-style fix: per table it caches
     given ``RangeSet`` (a device array reused by capture, application, and
     size estimation),
   * the materialized join layout per join spec (joined columns + the
-    fact-row back-map),
+    fact-row back-map); a sketch instance's join is a gather of its base
+    table's layout,
   * per-sketch *instances* (the filtered relation D_P), so an index hit
     re-executes over an already-materialized fragment subset,
   * cheap per-attribute statistics (distinct counts, non-negativity) used by
@@ -42,6 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.table import ColumnTable, encode_groups
+from repro.runtime.trace import count, span
 
 if TYPE_CHECKING:  # pragma: no cover - typing only
     from repro.core.ranges import RangeSet
@@ -216,7 +218,8 @@ class Catalog:
         "bucketize", "bucketize_delta", "frag_of_group",
         "fragment_sizes", "fragment_sizes_delta",
         "where_mask", "where_mask_instance", "where_mask_delta",
-        "join_materialize", "join_delta", "instance_build", "stacked_build",
+        "join_materialize", "join_delta", "join_instance", "instance_build",
+        "stacked_build",
         "distinct_count", "distinct_count_delta", "column_stats", "column_stats_delta",
     )
 
@@ -508,39 +511,85 @@ class Catalog:
         """Materialized equi-join (right key unique) + fact-row back-map.
 
         Fact rows with no partner are dropped (inner join); right-side columns
-        are prefixed with ``<right>.`` when their name collides.
+        are prefixed with ``<right>.`` when their name collides.  A sketch
+        instance whose base table's layout is cached joins by a gather of
+        that layout (``_join_instance``).  Each build runs under a
+        ``repro.join`` span and counts its rows and bytes into the current
+        trace record.
         """
-        key = (id(fact), id(right), left_key, right_key)
-        hit = self._joins.get(key)
-        if hit is not None and hit[0] is fact and hit[1] is right:
+        hit = self._cached_join(fact, right, left_key, right_key)
+        if hit is not None:
             self.stats["join_hit"] += 1
-            return hit[2], hit[3]
+            return hit
         d = fact.delta
+        parent = self._instance_parent(fact) if d is None else None
+        layout = (None if parent is None
+                  else self._cached_join(parent[0], right, left_key, right_key))
         if d is not None:
             p_joined, p_fact_idx = self.join(d.parent, right, left_key, right_key)
-            if d.kind == "append":
-                batch_cols = {a: np.asarray(d.appended[a]) for a in d.appended.schema}
-                cols_new, b_idx, _ = join_rows(batch_cols, right, left_key, right_key)
-                # Build the new joined table *as an append of its parent*, so
-                # the joined relation carries its own delta chain and its
-                # group encodings delta-refresh just like base tables'.
-                joined = p_joined.append({a: cols_new[a] for a in p_joined.schema})
-                fact_idx = np.concatenate([p_fact_idx, b_idx + d.parent.num_rows])
+        with span("repro.join"):
+            if d is not None:
+                if d.kind == "append":
+                    batch_cols = {a: np.asarray(d.appended[a]) for a in d.appended.schema}
+                    cols_new, b_idx, _ = join_rows(batch_cols, right, left_key, right_key)
+                    # Build the new joined table *as an append of its parent*,
+                    # so the joined relation carries its own delta chain and
+                    # its group encodings delta-refresh like base tables'.
+                    joined = p_joined.append({a: cols_new[a] for a in p_joined.schema})
+                    fact_idx = np.concatenate([p_fact_idx, b_idx + d.parent.num_rows])
+                else:
+                    keep_row = np.zeros(d.parent.num_rows, dtype=bool)
+                    keep_row[d.kept_idx] = True
+                    old_to_new = np.cumsum(keep_row) - 1
+                    joined_keep = keep_row[p_fact_idx]
+                    joined = p_joined.delete(~joined_keep)
+                    fact_idx = old_to_new[p_fact_idx[joined_keep]]
+                self.stats["join_delta"] += 1
+            elif layout is not None:
+                joined, fact_idx = self._join_instance(fact, *parent, *layout)
+                self.stats["join_instance"] += 1
             else:
-                keep_row = np.zeros(d.parent.num_rows, dtype=bool)
-                keep_row[d.kept_idx] = True
-                old_to_new = np.cumsum(keep_row) - 1
-                joined_keep = keep_row[p_fact_idx]
-                joined = p_joined.delete(~joined_keep)
-                fact_idx = old_to_new[p_fact_idx[joined_keep]]
-            self.stats["join_delta"] += 1
-            self._put(self._joins, key, (fact, right, joined, fact_idx))
-            return joined, fact_idx
-        self.stats["join_materialize"] += 1
-        cols, fact_idx, _ = join_rows(
-            {a: fact[a] for a in fact.schema}, right, left_key, right_key)
-        joined = ColumnTable(f"{fact.name}_join_{right.name}", cols, fact.primary_key)
-        self._put(self._joins, key, (fact, right, joined, fact_idx))
+                cols, fact_idx, _ = join_rows(
+                    {a: fact[a] for a in fact.schema}, right, left_key, right_key)
+                joined = ColumnTable(f"{fact.name}_join_{right.name}", cols, fact.primary_key)
+                self.stats["join_materialize"] += 1
+        count(join_rows=joined.num_rows,
+              join_bytes=sum(int(v.nbytes) for v in joined.columns.values()),
+              join_derived=int(layout is not None))
+        self._put(self._joins, (id(fact), id(right), left_key, right_key),
+                  (fact, right, joined, fact_idx))
+        return joined, fact_idx
+
+    def _cached_join(
+        self, fact: ColumnTable, right: ColumnTable, left_key: str, right_key: str
+    ) -> Optional[Tuple[ColumnTable, np.ndarray]]:
+        """``fact``'s cached join layout, or ``None`` (builds nothing)."""
+        hit = self._joins.get((id(fact), id(right), left_key, right_key))
+        if hit is not None and hit[0] is fact and hit[1] is right:
+            return hit[2], hit[3]
+        return None
+
+    def _join_instance(
+        self, instance: ColumnTable, base: ColumnTable, rows: np.ndarray,
+        layout: ColumnTable, layout_fact_idx: np.ndarray,
+    ) -> Tuple[ColumnTable, np.ndarray]:
+        """The join of a sketch instance, gathered from its base table's
+        layout: each instance row (padding included) whose base row has a
+        partner, in instance order, as ``join_rows`` would produce them from
+        scratch.  Instance-only columns (the padding's ``PAD_VALID``) come
+        from the instance.  The result is registered as a subset of the
+        layout, so its encodings and masks derive by a gather too."""
+        at = np.full(base.num_rows, -1, dtype=np.int64)  # layout row of each base row
+        at[layout_fact_idx] = np.arange(layout_fact_idx.shape[0])
+        pos = at[rows]
+        fact_idx = np.nonzero(pos >= 0)[0]
+        take = pos[fact_idx]
+        joined = layout.gather(jnp.asarray(take))
+        fact_take = jnp.asarray(fact_idx)
+        for a in instance.schema:
+            if not joined.has(a):
+                joined = joined.with_column(a, jnp.take(instance[a], fact_take, axis=0))
+        self._put(self._instance_rows, id(joined), (joined, layout, take))
         return joined, fact_idx
 
     # -- sketch instances (D_P) ----------------------------------------------

@@ -3,8 +3,10 @@
 A :class:`Record` holds what one query, or one ``run_batch`` call, did: its
 spans, each ``(name, start, end, parent)``, and its counters (host syncs,
 bytes copied device to host, catalog misses and evictions, and the names of
-the programs compiled while it was current).  ``RunInfo.trace`` carries a
-query's record, so it lives and dies with the ``RunInfo``.
+the programs compiled while it was current, and the rows and bytes of the
+join layouts it materialized).  ``RunInfo.trace`` carries a query's record,
+so it lives and dies with the ``RunInfo``; a query's record made inside a
+batch points to the batch's record (``Record.outer``).
 
 ``span(name)`` appends to the record that is current in this context, and
 also enters ``jax.profiler.TraceAnnotation(name)`` with the batch number and
@@ -68,6 +70,14 @@ class Record:
     catalog_misses: int = 0
     catalog_evictions: int = 0
     compiled: List[str] = dataclasses.field(default_factory=list)
+    # Join layouts materialized (``Catalog.join``): rows, device bytes, and
+    # how many were sketch instances gathered from a cached layout.
+    join_rows: int = 0
+    join_bytes: int = 0
+    join_derived: int = 0
+    # The batch's record, for a query's record made inside a batch: what the
+    # batch's shared spans counted lives there.
+    outer: Optional["Record"] = None
 
     def last(self, name: str) -> Optional[Span]:
         for s in reversed(self.spans):
@@ -130,6 +140,14 @@ def to_host(x) -> np.ndarray:
     return out
 
 
+def count(**counters: int) -> None:
+    """Add to the named counters of the current record, if one is current."""
+    rec = _STATE.get()[0]
+    if rec is not None:
+        for name, n in counters.items():
+            setattr(rec, name, getattr(rec, name) + n)
+
+
 def _on_event(name: str, secs: float, **kw) -> None:
     if name == COMPILE_EVENT:
         rec = _STATE.get()[0]
@@ -170,7 +188,9 @@ def query_record(pos: int) -> Record:
     """A fresh record for the query at ``pos`` of the current batch (a batch
     of its own when none is current)."""
     rec = _STATE.get()[0]
-    return Record(next(_BATCHES) if rec is None else rec.batch, pos)
+    if rec is None:
+        return Record(next(_BATCHES), pos)
+    return Record(rec.batch, pos, outer=rec if rec.pos is None else rec.outer)
 
 
 @contextlib.contextmanager

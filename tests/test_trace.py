@@ -276,3 +276,77 @@ def test_profiler_trace_holds_the_record_spans(db, queries, tmp_path):
     assert under("repro.select") == ["repro.select.aqr", "repro.select.estimate",
                                      "repro.select.sample"]
     assert under("repro.capture") == ["repro.capture.bitmap"]
+
+
+@pytest.fixture(scope="module")
+def star():
+    """Sales joined to stores; a fifth of the sales have no store, and the
+    first ten days sell more, so a sketch on ``s_day`` pays."""
+    from repro.core.table import from_numpy
+
+    rng = np.random.default_rng(3)
+    day = rng.integers(1, 40, 4000)
+    return Database({
+        "sales": from_numpy("sales", {
+            "s_store": rng.integers(1, 121, 4000).astype(np.int32),
+            "s_day": day.astype(np.int32),
+            "s_amount": (rng.random(4000) * np.where(day <= 10, 50, 10)).astype(np.float32)}),
+        "stores": from_numpy("stores", {
+            "st_key": np.arange(1, 101, dtype=np.int32),
+            "st_region": rng.integers(1, 9, 100).astype(np.int32)}),
+    })
+
+
+def _join_q(db, quantile):
+    from repro.core import JoinSpec
+
+    base = Query("sales", ("s_day",), Aggregate("sum", "s_amount"),
+                 join=JoinSpec("stores", "s_store", "st_key"))
+    thr = float(np.quantile(execute(base, db).values, quantile))
+    return Query(base.table, base.groupby, base.agg, join=base.join, having=Having(">", thr))
+
+
+def _joins(rec):
+    return [s for s in rec.spans if s.name == "repro.join"]
+
+
+def _nbytes(table):
+    return sum(int(v.nbytes) for v in table.columns.values())
+
+
+@pytest.mark.parametrize("batched", [False, True])
+def test_join_capture_records_its_join_spans_and_counters(star, batched):
+    """A capture over a join: its scan builds the join layout (one
+    ``repro.join`` under ``repro.scan``: on the query's record from ``run``,
+    on the batch's from ``run_batch``) and its warm-up gathers the joined
+    sketch instance (one under ``repro.capture.warm``).  The counters hold
+    the rows and bytes of what was materialized.  A hit over the cached
+    joined instance records none."""
+    eng = PBDSEngine(star)
+    q = _join_q(star, 0.5)
+    _, miss = eng.run_batch([q])[0] if batched else eng.run(q)
+    rec = miss.trace
+    assert miss.created and eng.catalog.stats["join_instance"] == 1
+    layout, _ = eng.catalog.join(star["sales"], star["stores"], "s_store", "st_key")
+    inst = eng.catalog.get_instance(eng.index.lookup_entry(q).sketch, star["sales"])
+    joined, _ = eng.catalog.join(inst, star["stores"], "s_store", "st_key")
+    assert eng.catalog.stats["join_hit"] >= 2  # both were cached
+    scan_rec = rec.outer if batched else rec
+    assert (rec.outer is not None) == batched
+    under = lambda r, name: [s for s in _joins(r) if s.parent.name == name]  # noqa: E731
+    assert len(under(scan_rec, "repro.scan")) == len(under(rec, "repro.capture.warm")) == 1
+    assert len(_joins(rec)) == (1 if batched else 2)
+    assert (scan_rec.join_rows, scan_rec.join_bytes) == (
+        (layout.num_rows, _nbytes(layout)) if batched else
+        (layout.num_rows + joined.num_rows, _nbytes(layout) + _nbytes(joined)))
+    if batched:
+        assert (rec.join_rows, rec.join_bytes) == (joined.num_rows, _nbytes(joined))
+        assert (rec.join_derived, rec.outer.join_derived) == (1, 0)
+    else:
+        assert rec.join_derived == 1
+    assert 0 < layout.num_rows < star["sales"].num_rows  # unmatched sales dropped
+
+    _, hit = eng.run_batch([_join_q(star, 0.8)])[0]
+    assert hit.reused and _joins(hit.trace) == []
+    assert (hit.trace.join_rows, hit.trace.join_bytes, hit.trace.join_derived) == (0, 0, 0)
+    assert _joins(hit.trace.outer) == [] and hit.trace.outer.join_rows == 0
