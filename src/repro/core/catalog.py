@@ -204,6 +204,8 @@ class Catalog:
         # ``groups``/``where_mask`` on a sketch instance gather from the base
         # table's cached products instead of fresh full host passes.
         self._instance_rows: Dict[int, Tuple[ColumnTable, ColumnTable, np.ndarray]] = {}
+        # Padded sketch instance, or a join of one -> (table, real rows).
+        self._valid_rows: Dict[int, Tuple[ColumnTable, int]] = {}
         # Stacked shard-major instances (``repro.core.shard``), keyed by
         # (registration key, table uid/version, plan identity) with a token
         # guard (per-shard table ids + sketch bits) so any shard-side delta
@@ -251,6 +253,7 @@ class Catalog:
         for k in [k for k, v in self._instance_rows.items()
                   if k == tid or v[1] is table]:
             del self._instance_rows[k]
+        self._valid_rows.pop(tid, None)
 
     def invalidate_chain(self, table: ColumnTable) -> None:
         """Invalidate ``table`` and every ancestor on its delta chain.
@@ -553,6 +556,11 @@ class Catalog:
                     {a: fact[a] for a in fact.schema}, right, left_key, right_key)
                 joined = ColumnTable(f"{fact.name}_join_{right.name}", cols, fact.primary_key)
                 self.stats["join_materialize"] += 1
+        valid = self._valid_rows.get(id(fact))
+        if valid is not None and valid[0] is fact:
+            # The padding is a tail of the instance, and a join keeps its
+            # rows in instance order.
+            self.note_valid_rows(joined, int(np.count_nonzero(fact_idx < valid[1])))
         count(join_rows=joined.num_rows,
               join_bytes=sum(int(v.nbytes) for v in joined.columns.values()),
               join_derived=int(layout is not None))
@@ -611,6 +619,16 @@ class Catalog:
             # gather (``groups`` / ``where_mask`` consult this first).
             self._put(self._instance_rows, id(instance),
                       (instance, table, np.asarray(rows)))
+
+    def note_valid_rows(self, table: ColumnTable, n: int) -> None:
+        """Record that only the first ``n`` rows of ``table`` are real: the
+        rest are padding (``PAD_VALID`` False)."""
+        self._put(self._valid_rows, id(table), (table, n))
+
+    def valid_rows(self, table: ColumnTable) -> int:
+        """The rows of ``table`` that are not padding."""
+        hit = self._valid_rows.get(id(table))
+        return hit[1] if hit is not None and hit[0] is table else table.num_rows
 
     def _instance_parent(
         self, table: ColumnTable

@@ -27,7 +27,8 @@ import numpy as np
 from repro.core.catalog import Catalog, default_catalog
 from repro.core.table import PAD_VALID, ColumnTable, Database
 from repro.runtime.guards import hot_path
-from repro.runtime.trace import span, to_host
+from repro.runtime.shapes import group_class, row_class
+from repro.runtime.trace import count, span, to_host
 
 Array = jax.Array
 
@@ -177,10 +178,6 @@ class QueryResult:
 # ---------------------------------------------------------------------------
 
 
-def _next_pow2(n: int) -> int:
-    return 1 << max(0, (n - 1)).bit_length()
-
-
 def segment_sums_counts(
     values: Array, gid: Array, n_groups: int, weights: Optional[Array] = None
 ) -> Tuple[Array, Array]:
@@ -188,16 +185,17 @@ def segment_sums_counts(
 
     Dispatches the Pallas one-hot MXU kernel on TPU and the segment-sum
     reference path elsewhere (see ``repro.kernels.ops``).  Row and group
-    dimensions are padded to powers of two so the jitted kernel wrapper
-    compiles once per size class instead of once per query shape.  The
-    mask goes to the kernel in its own dtype: its cast to float32 then runs
-    inside the kernel's jit, not as a dispatch of its own before it.
+    dimensions are padded to their size classes (``row_class``,
+    ``group_class``) so the jitted kernel wrapper compiles once per class
+    instead of once per query shape.  The mask goes to the kernel in its own
+    dtype: its cast to float32 then runs inside the kernel's jit, not as a
+    dispatch of its own before it.
     """
     from repro.kernels import ops as kops
 
     n = int(values.shape[0])
-    n_pad = _next_pow2(max(n, 1))
-    g_pad = _next_pow2(max(n_groups, 1))
+    n_pad = row_class(n)
+    g_pad = group_class(n_groups)
     w = jnp.ones(n, dtype=bool) if weights is None else weights
     if n_pad != n:
         # Padded rows carry weight 0 into group 0: they contribute nothing.
@@ -286,7 +284,7 @@ def inner_block_arrays(q: Query, flat: ColumnTable, catalog: Catalog):
             else jnp.ones(flat.num_rows, dtype=bool)
         )
         if flat.has(PAD_VALID):
-            # Pow2-padded sketch instance: the tail rows exist only to pin the
+            # Padded sketch instance: the tail rows exist only to pin the
             # compiled shape and must contribute nothing (weight 0 everywhere).
             where_mask = where_mask & flat[PAD_VALID]
     with span("repro.groups"):
@@ -303,8 +301,12 @@ def inner_group_partials(
 ):
     """WHERE mask + group encoding + fused per-group sums/counts over one
     (already joined) flat table.  Returns ``(enc, where_mask, sums, counts)``.
+    Counts the rows and groups the aggregate covers, and the size classes
+    it runs them at, into the current trace record (``agg_*``).
     """
     enc, where_mask, vals = inner_block_arrays(q, flat, catalog)
+    count(agg_rows=catalog.valid_rows(flat), agg_rows_padded=row_class(flat.num_rows),
+          agg_groups=enc.n_groups, agg_groups_padded=group_class(enc.n_groups))
     with span("repro.aggregate"):
         sums, counts = segment_sums_counts(vals, enc.gid_dev, enc.n_groups, weights=where_mask)
     return enc, where_mask, sums, counts

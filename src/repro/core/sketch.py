@@ -28,6 +28,7 @@ from repro.core.queries import (
 )
 from repro.core.ranges import RangeSet
 from repro.core.table import PAD_VALID, ColumnTable, Database
+from repro.runtime.shapes import row_class
 from repro.runtime.trace import span, to_host
 
 Array = jax.Array
@@ -188,26 +189,28 @@ def sketch_keep_mask(
     return jnp.asarray(sketch.bits)[bucket]
 
 
-def _pad_instance_pow2(
+def _pad_instance(
     instance: ColumnTable, rows: np.ndarray, catalog: Catalog
 ) -> Tuple[ColumnTable, np.ndarray]:
-    """Pow2-pad an instance's row count with masked (weight-0) tail rows.
+    """Pad an instance's row count to its size class (``row_class``) with
+    masked (weight-0) tail rows.
 
     Steady-state reuse executes over catalog-cached instances whose row
     counts drift with every repair (a handful of rows per mutation), and each
     fresh count is a fresh XLA shape — a recompile on the hot path.  Padding
-    every instance to the next power of two quantizes the shape space so a
-    repaired instance almost always lands in an already-compiled size class.
-    The tail rows duplicate row 0 and carry ``PAD_VALID=False``; the executor
+    every instance to its class quantizes the shape space so a repaired
+    instance almost always lands in an already-compiled size class.  The
+    tail rows duplicate row 0 and carry ``PAD_VALID=False``; the executor
     zero-weights them, so results are bit-identical (adding 0.0 terms to the
     f32 segment sums is exact).  ``rows`` (the base-table row index per
     instance row) is padded alongside for the catalog's subset-derived
-    encodings.
+    encodings, and the catalog keeps the count of real rows
+    (``Catalog.valid_rows``).
     """
     n = instance.num_rows
     if n == 0:
         return instance, rows
-    n_pad = 1 << (n - 1).bit_length()
+    n_pad = row_class(n)
     valid = np.zeros(n_pad, dtype=bool)
     valid[:n] = True
     if n_pad != n:
@@ -216,8 +219,9 @@ def _pad_instance_pow2(
         instance = instance.gather(jnp.asarray(idx))
         rows = rows[idx]
         catalog.stats["instance_padded"] += 1
-    return (instance.with_column(PAD_VALID, jnp.asarray(valid[:instance.num_rows])),
-            rows)
+    instance = instance.with_column(PAD_VALID, jnp.asarray(valid[:instance.num_rows]))
+    catalog.note_valid_rows(instance, n)
+    return instance, rows
 
 
 def _build_instance(
@@ -227,10 +231,11 @@ def _build_instance(
 
     Clustered tables on the sketch's own partition skip fragments by slicing;
     everything else falls back to the per-row keep-mask kernel.  Either way
-    the rows are pow2-padded (masked tail) so reuse execution over the cached
-    instance hits an already-compiled shape, and the base-row map rides along
-    so group encodings / WHERE masks of the instance derive from the base
-    table's cached ones by an O(n) gather instead of fresh host passes.
+    the rows are padded to their size class (masked tail) so reuse execution
+    over the cached instance hits an already-compiled shape, and the base-row
+    map rides along so group encodings / WHERE masks of the instance derive
+    from the base table's cached ones by an O(n) gather instead of fresh host
+    passes.
     """
     lay = table.layout
     if lay is not None and lay.matches(sketch.ranges):
@@ -246,11 +251,11 @@ def _build_instance(
                 catalog.bucketize(table, sketch.ranges))[n - lay.tail:]
         inst, rows = table.take_fragments(
             frag_ids, tail_bucket=tail_bucket, return_rows=True)
-        return _pad_instance_pow2(inst, rows, catalog)
+        return _pad_instance(inst, rows, catalog)
     catalog.stats["instance_mask"] += 1
     mask = sketch_keep_mask(sketch, table, catalog=catalog)
     rows = np.nonzero(to_host(mask))[0]  # analyze: waive[SYNC01]: the kept rows' positions, once per instance build
-    return _pad_instance_pow2(table.gather(jnp.asarray(rows)), rows, catalog)
+    return _pad_instance(table.gather(jnp.asarray(rows)), rows, catalog)
 
 
 def apply_sketch(

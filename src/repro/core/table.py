@@ -24,7 +24,7 @@ Array = jax.Array
 # apart from "a different relation entirely".
 _TABLE_UIDS = itertools.count(1)
 
-# Reserved column marking pow2-padded tables (sketch instances): True for
+# Reserved column marking padded tables (sketch instances): True for
 # real rows, False for the shape-pinning tail.  The executor folds it into
 # the aggregation weights so padded and unpadded execution agree bit-for-bit.
 PAD_VALID = "__valid__"

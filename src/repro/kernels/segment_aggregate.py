@@ -80,6 +80,25 @@ def _row_chunk(onehot_rows: int) -> int:
     return max(LANE, min(MAX_CHUNK, c))
 
 
+def _group_tiling(n_groups: int):
+    """``(lo_width, hi_rows, hi_block, n_gblocks)`` of a launch over
+    ``n_groups`` groups: fewer than 128 groups take a narrower lo one-hot (16
+    to 128 lanes), and a block's hi rows pad to the bf16 tile's 16."""
+    lo_width = min(LANE, max(BF16_SUBLANE, 1 << (n_groups - 1).bit_length()))
+    hi_rows = -(-n_groups // lo_width)
+    hi_block = min(MAX_HI_BLOCK, hi_rows + (-hi_rows % BF16_SUBLANE))
+    return lo_width, hi_rows, hi_block, -(-hi_rows // hi_block)
+
+
+def padded_groups(n_groups: int) -> int:
+    """The group count a launch over ``n_groups`` groups pads to: its lo
+    one-hot's width where one hi row holds every group, else its whole
+    blocks of hi rows, 128 groups a row.  Every count from ``n_groups`` up
+    to it tiles the same way, at the same cost."""
+    lo_width, hi_rows, hi_block, n_gblocks = _group_tiling(n_groups)
+    return lo_width if hi_rows == 1 else n_gblocks * hi_block * lo_width
+
+
 def _segagg_kernel(gid_ref, val_ref, w_ref, out_ref, *, hi_block: int, lo_width: int,
                    chunk: int):
     g = pl.program_id(1)
@@ -132,11 +151,7 @@ def segment_aggregate_batch_pallas(
     w = (jnp.ones_like(values, dtype=jnp.float32) if weights is None
          else weights.astype(jnp.float32))
     k = 4  # three parts of v * w, then w
-    # Fewer than 128 groups take a narrower lo one-hot: 16 to 128 lanes.
-    lo_width = min(LANE, max(BF16_SUBLANE, 1 << (n_groups - 1).bit_length()))
-    hi_rows = -(-n_groups // lo_width)
-    hi_block = min(MAX_HI_BLOCK, hi_rows + (-hi_rows % BF16_SUBLANE))
-    n_gblocks = -(-hi_rows // hi_block)
+    lo_width, _, hi_block, n_gblocks = _group_tiling(n_groups)
     # Short inputs take a short chunk rather than padding to a full tile.
     chunk = min(_row_chunk(k * hi_block + lo_width),
                 max(LANE, 1 << (-(-n // SUBLANE) - 1).bit_length()))

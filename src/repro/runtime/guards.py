@@ -1,9 +1,10 @@
 """Runtime sanitizer layer: the invariants the hot path rests on, enforced.
 
 Every fast path this repo ships rests on invariants the type system cannot
-see: pow2-padded shapes so steady-state reuse compiles zero XLA programs,
-PRNG keys never reused across passes, one launch per served hit batch,
-device values never synced mid-loop.  ``tools.analyze`` checks what a static
+see: shapes padded to size classes (``runtime/shapes.py``, powers of two
+elsewhere) so steady-state reuse compiles zero XLA programs, PRNG keys
+never reused across passes, one launch per served hit batch, device values
+never synced mid-loop.  ``tools.analyze`` checks what a static
 pass can see at review time; this module is the *runtime* half — shared
 telemetry counters and guard context managers that turn "should never
 happen" into a raised error in tests.
@@ -64,8 +65,9 @@ def hot_path(fn: F) -> F:
     Zero runtime cost (no wrapper): sets ``__hot_path__`` and records the
     qualified name so tooling — and humans reading the code — know the
     function is subject to the hot-path invariants (no host-device sync,
-    pow2-padded shapes, no per-call retraces).  ``tools.analyze`` discovers
-    the decorator syntactically, so decorating never imports the analyzer.
+    shapes padded to size classes, no per-call retraces).  ``tools.analyze``
+    discovers the decorator syntactically, so decorating never imports the
+    analyzer.
     """
     HOT_PATHS.append(f"{fn.__module__}.{fn.__qualname__}")
     fn.__hot_path__ = True  # type: ignore[attr-defined]
@@ -104,7 +106,7 @@ def retrace_guard(allowed: Optional[int] = 0, label: str = "") -> Iterator[Compi
     to assert that warmup *did* compile).  Counts real backend
     compilations — tracing that hits the executable cache emits no event —
     which is exactly the "steady state compiles nothing new" contract the
-    pow2 padding exists to uphold.
+    size-class padding exists to uphold.
     """
     from jax import monitoring
 

@@ -3,8 +3,9 @@
 A :class:`Record` holds what one query, or one ``run_batch`` call, did: its
 spans, each ``(name, start, end, parent)``, and its counters (host syncs,
 bytes copied device to host, catalog misses and evictions, and the names of
-the programs compiled while it was current, and the rows and bytes of the
-join layouts it materialized).  ``RunInfo.trace`` carries a query's record,
+the programs compiled while it was current, the rows and bytes of the
+join layouts it materialized, and the rows and groups its segment
+aggregates covered and ran).  ``RunInfo.trace`` carries a query's record,
 so it lives and dies with the ``RunInfo``; a query's record made inside a
 batch points to the batch's record (``Record.outer``).
 
@@ -75,6 +76,12 @@ class Record:
     join_rows: int = 0
     join_bytes: int = 0
     join_derived: int = 0
+    # Segment aggregates run (``queries.inner_group_partials``): real rows
+    # and groups, and the size classes the kernel ran them at.
+    agg_rows: int = 0
+    agg_rows_padded: int = 0
+    agg_groups: int = 0
+    agg_groups_padded: int = 0
     # The batch's record, for a query's record made inside a batch: what the
     # batch's shared spans counted lives there.
     outer: Optional["Record"] = None

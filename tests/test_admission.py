@@ -8,7 +8,7 @@ pass + one inner-block scan + one capture launch per signature group).
 
 Also covered: the batched capture kernel against the per-mask oracle, the
 multi-query padded estimator against the single-query path, and the
-steady-state recompile guarantee (pow2-padded instances + pow2-quantized
+steady-state recompile guarantee (row-class-padded instances + pow2-quantized
 selection shapes => zero new XLA compilations after warmup).
 """
 import contextlib
@@ -256,8 +256,9 @@ def test_shared_miss_path_work(tpch_db):
 
 
 def test_steady_state_reuse_zero_recompiles(tpch_db):
-    """After warmup, reuse over pow2-padded instances compiles nothing new —
-    even after a small mutation + repair shifts every instance's row count."""
+    """After warmup, reuse over instances padded to their row class compiles
+    nothing new — even after a small mutation + repair shifts every
+    instance's row count."""
     qs = _template_batches(tpch_db, (0.97, 0.94))["Q-AGH"]
     eng = PBDSEngine(tpch_db, strategy="CB-OPT-GB", n_ranges=40, theta=0.1,
                      seed=0, min_selectivity_gain=0.98)
@@ -270,8 +271,8 @@ def test_steady_state_reuse_zero_recompiles(tpch_db):
     assert all(out[i][1].reused for i in created)
     assert len(events) == 0, f"steady-state reuse compiled {len(events)} programs"
 
-    # A small append shifts the logical instance sizes; pow2 padding keeps
-    # the physical shapes in the same compiled size class.
+    # A small append shifts the logical instance sizes; padding to the row
+    # class keeps the physical shapes in the same compiled size class.
     fact = eng.db["lineitem"]
     batch = {a: np.asarray(fact[a])[:64] for a in fact.schema}
     eng.append_rows("lineitem", batch)

@@ -43,6 +43,15 @@ def test_good_fixture_is_clean(rule):
     assert not found, [f.format() for f in found]
 
 
+def test_pad01_accepts_size_classes_and_still_flags_raw_sizes():
+    """Sizes routed through ``row_class`` / ``group_class`` pass PAD01; a
+    raw ``len(rows)`` in the same function is still flagged, and alone."""
+    found = _rule_findings("pad01_size_class.py", "PAD01")
+    lines = (FIXTURES / "pad01_size_class.py").read_text().splitlines()
+    assert [lines[f.line - 1].strip() for f in found] == [
+        "raw = jnp.ones(len(rows))  # raw row count: still one size class per len"]
+
+
 def test_pr7_key_reuse_reconstruction_flagged():
     """The minimized select_attribute bug: one key into two random passes."""
     found = _rule_findings("key01_bad.py", "KEY01")

@@ -170,7 +170,7 @@ def test_data_pipeline_determinism_and_skipping():
     prov = provenance_mask(spec.query(), Database({"corpus": meta}))
     prov_docs = set(np.asarray(meta["doc_id"])[prov].tolist())
     assert prov_docs <= set(p1.selected_docs.tolist())
-    # Regression: pow2-padded sketch instances duplicate masked rows — the
+    # Regression: padded sketch instances duplicate masked rows — the
     # pipeline must filter them out, never oversample a document.
     assert len(p1.selected_docs) == len(set(p1.selected_docs.tolist()))
 

@@ -13,8 +13,9 @@ import pytest
 
 from repro.core import Aggregate, Catalog, Database, Having, JoinSpec, PBDSEngine, Query
 from repro.core.queries import Predicate, execute, inner_group_partials
-from repro.core.sketch import _pad_instance_pow2
+from repro.core.sketch import _pad_instance
 from repro.core.table import PAD_VALID, from_numpy
+from repro.runtime.shapes import row_class
 
 N_FACT, N_DIM = 3_000, 400
 
@@ -43,11 +44,11 @@ def db():
 
 
 def _instance(db, catalog, n_rows):
-    """A sketch instance of ``n_rows`` fact rows, pow2-padded and registered
-    as the engine registers one."""
+    """A sketch instance of ``n_rows`` fact rows, padded to its row class and
+    registered as the engine registers one."""
     fact = db["fact"]
     rows = np.sort(np.random.default_rng(n_rows).choice(fact.num_rows, n_rows, replace=False))
-    inst, rows = _pad_instance_pow2(fact.gather(rows), rows, catalog)
+    inst, rows = _pad_instance(fact.gather(rows), rows, catalog)
     catalog.put_instance(object(), fact, inst, rows=rows)
     return inst
 
@@ -71,7 +72,8 @@ def test_gathered_join_equals_the_join_from_scratch(db, n_rows):
     cat_s, _, scratch, idx_s = _joined(db, n_rows, layout_cached=False)
     assert cat_g.stats["join_instance"] == 1 and cat_g.stats["join_materialize"] == 1
     assert cat_s.stats["join_instance"] == 0 and cat_s.stats["join_materialize"] == 1
-    assert inst.num_rows == 1 << (n_rows - 1).bit_length() and inst.has(PAD_VALID)
+    assert inst.num_rows == row_class(n_rows) and inst.has(PAD_VALID)
+    assert cat_g.valid_rows(inst) == n_rows
     assert gathered.name == scratch.name and gathered.primary_key == scratch.primary_key
     assert gathered.schema == scratch.schema
     for a in scratch.schema:

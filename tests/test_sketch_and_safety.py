@@ -8,6 +8,7 @@ from repro.core import (
     is_safe_sketch, prefilter_candidates, safe_attributes, subsumes,
 )
 from repro.core.datasets import make_crimes
+from repro.runtime.shapes import row_class
 
 
 @pytest.fixture(scope="module")
@@ -91,14 +92,14 @@ def test_apply_sketch_shrinks_db(db):
     qs = dataclasses.replace(base, having=Having(">", float(np.quantile(sums, 0.99))))
     sk = capture_sketch(qs, db, equi_depth_ranges(db["crimes"], "beat", 50))
     db2 = apply_sketch(sk, db)
-    # Instances are pow2-padded (masked tail) so reuse execution hits an
-    # already-compiled shape: logical rows == size_rows, physical rows are
-    # the next power of two.
+    # Instances are padded to their row class (masked tail) so reuse
+    # execution hits an already-compiled shape: logical rows == size_rows,
+    # physical rows are the row class of that count.
     from repro.core.table import PAD_VALID
 
     inst = db2["crimes"]
     assert int(np.asarray(inst[PAD_VALID]).sum()) == sk.size_rows
-    assert inst.num_rows == 1 << (sk.size_rows - 1).bit_length()
+    assert inst.num_rows == row_class(sk.size_rows)
     assert inst.num_rows < db["crimes"].num_rows
 
 

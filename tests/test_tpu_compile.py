@@ -3,7 +3,8 @@
 The TPU compiler is installed beside the CPU backend, so the Pallas kernels
 and the 4-device ``shard_map`` launch compile here for a ``v5e:2x2``
 topology at deployment widths: 8M rows (a 6.7M-row table padded to its
-power-of-two size class), 128 to 65,536 groups, 1,000 ranges, batch 16.
+power-of-two size class; the single-node segment aggregate at 6,815,744,
+its row class), 128 to 65,536 groups, 1,000 ranges, batch 16.
 Interpret mode cannot see what Mosaic refuses (layouts, tiling, VMEM over
 the scoped limit); these compiles can.  Nothing runs, so results and times
 come only from the chip.
@@ -20,8 +21,10 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from repro.kernels import ops
+from repro.runtime.shapes import row_class
 
 ROWS = 1 << 23
+HIT_ROWS = row_class(6_700_000)
 RANGES = 1000
 BATCH = 16
 HBM_BYTES = 16 * 10 ** 9  # one v5e chip
@@ -75,10 +78,10 @@ def _assert_fits(compiled):
     assert total < HBM_BYTES, total
 
 
-@pytest.mark.parametrize("n_groups", [512, 128, 8192, 16384, 65536])
+@pytest.mark.parametrize("n_groups", [512, 128, 8192, 16384, 65536, 10240, 12288])
 def test_segment_aggregate_compiles(one_chip, n_groups):
-    f32 = jax.ShapeDtypeStruct((ROWS,), jnp.float32, sharding=one_chip)
-    i32 = jax.ShapeDtypeStruct((ROWS,), jnp.int32, sharding=one_chip)
+    f32 = jax.ShapeDtypeStruct((HIT_ROWS,), jnp.float32, sharding=one_chip)
+    i32 = jax.ShapeDtypeStruct((HIT_ROWS,), jnp.int32, sharding=one_chip)
     compiled = _compile(
         lambda v, g, w: ops.segment_aggregate(v, g, n_groups, w), f32, i32, f32)
     _assert_fits(compiled)

@@ -13,7 +13,9 @@ from repro.core import Aggregate, Database, Having, PBDSEngine, Query
 from repro.core.datasets import make_crimes
 from repro.core.queries import execute
 from repro.core.strategies import SelectionConfig
+from repro.core.table import PAD_VALID
 from repro.runtime import trace
+from repro.runtime.shapes import group_class, row_class
 
 HIT_CHILDREN = ["repro.probe", "repro.repair", "repro.instance", "repro.where",
                 "repro.groups", "repro.aggregate", "repro.to_host", "repro.to_host",
@@ -100,6 +102,25 @@ def test_hit_copies_three_arrays(served, queries):
         # byte per padded instance row)
         assert info.trace.host_syncs == 3
         assert info.trace.d2h_bytes == 2 * 4 * n_groups + inst.num_rows
+
+
+def test_hit_counts_the_rows_and_groups_it_aggregates(served, queries):
+    """A hit's record counts the instance's real rows and its groups, and
+    the size classes its aggregate ran them at; the miss wave's two shared
+    scans count the whole table twice on the batch's record."""
+    eng, wave, hits = served
+    for q, (_, info) in zip([queries[2], queries[0], queries[1]], hits):
+        sketch = eng.index.lookup_entry(q).sketch
+        inst = eng.catalog.get_instance(sketch, eng.db["crimes"])
+        n_groups = eng.catalog.groups(inst, q.groupby).n_groups
+        rec = info.trace
+        assert rec.agg_rows == sketch.size_rows == int(np.asarray(inst[PAD_VALID]).sum())
+        assert rec.agg_rows_padded == row_class(sketch.size_rows) == inst.num_rows
+        assert (rec.agg_groups, rec.agg_groups_padded) == (n_groups, group_class(n_groups))
+        assert rec.outer.agg_rows == 0
+    n = eng.db["crimes"].num_rows
+    outer = wave[0][1].trace.outer
+    assert (outer.agg_rows, outer.agg_rows_padded) == (2 * n, 2 * row_class(n))
 
 
 def test_miss_wave_span_tree(served):
@@ -350,3 +371,21 @@ def test_join_capture_records_its_join_spans_and_counters(star, batched):
     assert hit.reused and _joins(hit.trace) == []
     assert (hit.trace.join_rows, hit.trace.join_bytes, hit.trace.join_derived) == (0, 0, 0)
     assert _joins(hit.trace.outer) == [] and hit.trace.outer.join_rows == 0
+
+
+def test_join_hit_counts_the_joined_instances_real_rows(star):
+    """Over a joined instance a hit counts the joined rows that come from
+    the instance's real rows (sales without a store are gone, padding rows
+    are kept), and the row class of all of them."""
+    eng = PBDSEngine(star)
+    eng.run_batch([_join_q(star, 0.5)])
+    q = _join_q(star, 0.8)
+    _, hit = eng.run_batch([q])[0]
+    assert hit.reused
+    sketch = eng.index.lookup_entry(q).sketch
+    inst = eng.catalog.get_instance(sketch, star["sales"])
+    joined, _ = eng.catalog.join(inst, star["stores"], "s_store", "st_key")
+    real = int(np.asarray(joined[PAD_VALID]).sum())
+    assert 0 < real < sketch.size_rows and joined.num_rows > real
+    assert hit.trace.agg_rows == real == eng.catalog.valid_rows(joined)
+    assert hit.trace.agg_rows_padded == row_class(joined.num_rows)

@@ -13,7 +13,7 @@ KEY01   PRNG key reuse: the same key object flowing into two consumers
         without an intervening ``split``/``fold_in`` (the PR 7
         ``select_attribute`` AQR-key bug).
 PAD01   Shape hazards: dynamic-shaped array constructors on hot paths that
-        bypass the shared pow2 helpers (retrace bombs).
+        bypass the shared size-class helpers (retrace bombs).
 SYNC01  Host-device sync on hot paths: ``.item()`` / ``float()`` / ``int()``
         / ``np.asarray`` on device-derived values inside functions reachable
         from the ``@hot_path`` roots.
